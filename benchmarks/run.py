@@ -41,6 +41,7 @@ import time
 import traceback
 
 from repro.federated.telemetry import Telemetry, dispatch_summary, set_telemetry
+from repro.launch.compile_cache import enable_compile_cache
 
 MODULES = [
     "bench_costs",
@@ -85,6 +86,7 @@ def main() -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="small configs (CI budget) where supported")
     args = ap.parse_args()
+    enable_compile_cache()
     only = args.names or None
     print("name,us_per_call,derived")
     failures = []

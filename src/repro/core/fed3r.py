@@ -30,6 +30,16 @@ from typing import NamedTuple, Optional, Sequence, Union
 import jax
 import jax.numpy as jnp
 
+# The closed form is fp32 algebra.  On a TPU, XLA's default contracts fp32
+# operands in one bf16 pass (8 mantissa bits), so every Gram, statistics
+# and score product of the algebra asks for fp32; on CPU this is a no-op.
+FP32 = jax.lax.Precision.HIGHEST
+
+
+def gram(a: jax.Array, b: Optional[jax.Array] = None) -> jax.Array:
+    """aᵀb (aᵀa without ``b``) over the leading dim, contracted at fp32."""
+    return jnp.matmul(a.T, a if b is None else b, precision=FP32)
+
 
 class Fed3RStats(NamedTuple):
     """Sufficient statistics of the ridge-regression classifier."""
@@ -84,7 +94,7 @@ def client_stats(
     batching in the distributed runtime) while keeping the sums exact.
     """
     z, y, n = masked_design(features, labels, n_classes, mask)
-    return Fed3RStats(A=z.T @ z, b=z.T @ y, n=n)
+    return Fed3RStats(A=gram(z), b=gram(z, y), n=n)
 
 
 def merge(*stats: Fed3RStats) -> Fed3RStats:
@@ -202,8 +212,8 @@ def factored_update(
     form of the two GEMMs lives in :func:`repro.kernels.chol_gram`.
     """
     z, y, _ = masked_design(features, labels, state.b.shape[1], mask)
-    G = state.L @ state.L.T + z.T @ z
-    return Fed3RFactored(L=jnp.linalg.cholesky(G), b=state.b + z.T @ y)
+    G = gram(state.L.T) + gram(z)
+    return Fed3RFactored(L=jnp.linalg.cholesky(G), b=state.b + gram(z, y))
 
 
 def factored_solution(state: Fed3RFactored, normalize: bool = True) -> jax.Array:
@@ -245,7 +255,7 @@ def personalized_solution(
     :class:`repro.federated.personalization.PersonalizationEngine`.
     """
     a = jnp.asarray(alpha, jnp.float32)
-    L_pers = jnp.linalg.cholesky(state.L @ state.L.T + a * client.A)
+    L_pers = jnp.linalg.cholesky(gram(state.L.T) + a * client.A)
     L_use = jnp.where(a == 0.0, state.L, L_pers)
     rhs = jnp.where(a == 0.0, state.b, state.b + a * client.b)
     W = jax.scipy.linalg.cho_solve((L_use, True), rhs)

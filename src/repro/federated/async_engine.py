@@ -376,7 +376,7 @@ class AsyncRoundEngine(DistDispatchMixin):
         fold bitwise.
         """
         S_A, S_b, S_n = self.dist.all_reduce((S_A, S_b, S_n))
-        G = L @ L.T + S_A
+        G = fed3r.gram(L.T) + S_A
         if self.cfg.secure:
             # shared-scale int8-valued payloads: same error model as int8
             Lp = compress.psd_cholesky(
@@ -429,7 +429,7 @@ class AsyncRoundEngine(DistDispatchMixin):
         S_A = jnp.sum(state.A_slots, axis=(0, 1))
         S_b = jnp.sum(state.b_slots, axis=(0, 1))
         S_A, S_b = self.dist.all_reduce((S_A, S_b))
-        G = state.L @ state.L.T + S_A
+        G = fed3r.gram(state.L.T) + S_A
         if self.wire.kind in ("int8", "fp8"):
             L = compress.psd_cholesky(
                 G, compress.quant_spectral_bound(S_A, self.wire)

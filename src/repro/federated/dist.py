@@ -2,16 +2,13 @@
 
 Before this module, every engine (batch statistics, rounds, streaming,
 personalization) carried its own copy of the same plumbing: the
-``use_kernel`` auto-resolution, the ``donate_argnums`` backend policy, the
+``use_kernel`` auto-resolution, the donation policy, the
 ``merge|psum`` aggregation validation, a host-side dispatch counter, and —
 for mesh runs — an externally-applied ``shard_map`` the caller had to
 assemble by hand.  This module owns all of it:
 
 * :func:`resolve_use_kernel` — ONE definition of the Pallas-vs-XLA auto
   rule (compiled Pallas on TPU; XLA GEMMs elsewhere).
-* :func:`donate_argnums` — ONE definition of the donation policy (donate
-  the carried state everywhere except CPU, where XLA ignores donation and
-  warns).
 * :class:`DistConfig` — the shared distributed-execution configuration the
   per-engine ``aggregation``/``mesh_axes``/``donate`` fields migrated
   into.  ``mesh=None`` keeps today's behavior (plain jit; ``"psum"`` mode
@@ -62,6 +59,8 @@ from functools import partial
 from typing import Any, Callable, Optional, Tuple
 
 import jax
+import jax.numpy as jnp
+from jax.sharding import NamedSharding
 
 from repro.federated.telemetry import Telemetry, get_telemetry
 from repro.launch.mesh import data_axes, data_parallel_size
@@ -74,12 +73,6 @@ def resolve_use_kernel(use_kernel: Optional[bool]) -> bool:
     return jax.default_backend() == "tpu" if use_kernel is None else use_kernel
 
 
-def donate_argnums(donate: bool, argnums: Tuple[int, ...] = (0,)) -> Tuple[int, ...]:
-    """The shared donation policy: donate the carried state to the dispatch
-    everywhere except CPU, where XLA ignores donation (and warns)."""
-    return argnums if donate and jax.default_backend() != "cpu" else ()
-
-
 def validate_backend(aggregation: str, axis_names: Tuple[str, ...]) -> None:
     """The merge|psum validation every engine used to re-implement."""
     if aggregation not in ("merge", "psum"):
@@ -89,22 +82,11 @@ def validate_backend(aggregation: str, axis_names: Tuple[str, ...]) -> None:
 
 
 def _shard_map(fn: Callable, mesh, in_specs, out_specs) -> Callable:
-    """Version-portable shard_map (``jax.shard_map`` when public, else the
-    ``jax.experimental`` path), replication checking off: engine outputs are
+    """``jax.shard_map`` with replication checking off: engine outputs are
     made replicated by explicit psums, not by tracked rep-sets, and the
     Pallas kernels inside the cores have no rep rules."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        try:
-            return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=False)
-        except TypeError:  # older signature spells it check_rep
-            return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
-    from jax.experimental.shard_map import shard_map as sm_experimental
-
-    return sm_experimental(fn, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def shard_cohort(
@@ -168,7 +150,7 @@ def dist_jit(
     aggregation).  ``mesh=Mesh``: ``jax.jit(shard_map(fn, mesh, in_specs,
     out_specs))`` — the engine core runs as one SPMD program per device
     over its shard of the batch-carrying axis, still ONE host dispatch.
-    ``donate`` is already-resolved argnums (see :func:`donate_argnums`).
+    ``donate`` is the argnums whose buffers the program may reuse.
     """
     if mesh is not None:
         fn = _shard_map(fn, mesh, in_specs, out_specs)
@@ -192,7 +174,7 @@ class DistConfig:
     ``mesh_axes`` names the reduce axes explicitly; empty with a ``mesh``
     defaults to every non-``"model"`` axis of the mesh (``("pod", "data")``
     on the multi-pod production mesh).  ``donate`` is the donate-the-state
-    policy (applied through :func:`donate_argnums`).
+    policy, the same on every backend.
 
     ``tree`` routes :meth:`DistContext.all_reduce` through an N-tier
     :class:`repro.federated.tiers.AggregationTree` instead of the
@@ -342,6 +324,16 @@ class DistContext:
             return None
         return data_parallel_spec(self.cfg.axis_names, axis)
 
+    def place(self, x: Any, axis: int = 0) -> jax.Array:
+        """Put a host batch array on device for the program: in mesh mode
+        straight into its data sharding along dim ``axis``, so each device
+        receives only its own shard; otherwise onto the default device."""
+        if self.cfg.mesh is None:
+            return jnp.asarray(x)
+        return jax.device_put(
+            x, NamedSharding(self.cfg.mesh, self.data_spec(axis))
+        )
+
     def jit(
         self,
         fn: Callable,
@@ -363,7 +355,7 @@ class DistContext:
             mesh=self.cfg.mesh,
             in_specs=in_specs,
             out_specs=out_specs,
-            donate=donate_argnums(want, donate_argnums_),
+            donate=donate_argnums_ if want else (),
         )
 
 

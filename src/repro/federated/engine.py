@@ -74,7 +74,7 @@ def _ab(z: jax.Array, y: jax.Array, use_kernel: Optional[bool]):
     """The (A, b) GEMM backend over masked design matrices."""
     if resolve_use_kernel(use_kernel):
         return fed3r_stats_kernel(z, y)
-    return z.T @ z, z.T @ y
+    return fed3r.gram(z), fed3r.gram(z, y)
 
 
 def shard_stats(
@@ -251,13 +251,19 @@ class AccumulationEngine(DistDispatchMixin):
         """Fold a packed client selection into the accumulator (one dispatch)."""
         with self.dist.telemetry.span("accumulate", engine="accumulation"):
             self.dist.dispatch()
-            return self._accumulate(
-                acc,
-                jnp.asarray(packed.inputs),
-                jnp.asarray(packed.labels),
-                jnp.asarray(packed.mask),
-                params,
-            )
+            return self._accumulate(acc, *self._args(packed), params)
+
+    def lower(
+        self, acc: EngineStats, packed: PackedClients, params: Any = None
+    ) -> jax.stages.Lowered:
+        """The accumulation program for this packing, lowered without
+        running it (its compiled text shows which kernels it holds)."""
+        return self._accumulate.lower(acc, *self._args(packed), params)
+
+    def _args(self, packed: PackedClients):
+        return tuple(
+            self.dist.place(a) for a in (packed.inputs, packed.labels, packed.mask)
+        )
 
     def reduce_payloads(self, payloads, tree) -> EngineStats:
         """The host-side tiered fold entry point: reduce ``tree.leaves``

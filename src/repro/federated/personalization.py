@@ -46,7 +46,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import fed3r
-from repro.core.fed3r import Fed3RFactored, Fed3RStats
+from repro.core.fed3r import FP32, Fed3RFactored, Fed3RStats
 from repro.data.pipeline import PackedPersonalCohort
 from repro.federated.dist import (
     DistConfig,
@@ -166,8 +166,8 @@ class PersonalizationEngine(DistDispatchMixin):
         if self._use_kernel():
             G, B = batched_chol_gram_kernel(L, zs, ys)
         else:
-            G = L @ L.T + jnp.einsum("knd,kne->kde", zs, zs)
-            B = jnp.einsum("knd,knc->kdc", zs, ys)
+            G = fed3r.gram(L.T) + jnp.einsum("knd,kne->kde", zs, zs, precision=FP32)
+            B = jnp.einsum("knd,knc->kdc", zs, ys, precision=FP32)
         Lk = jnp.linalg.cholesky(G)
         Wp = self._batched_solve(Lk, b[None] + B)
         Wg = fed3r.factored_solution(
@@ -193,10 +193,10 @@ class PersonalizationEngine(DistDispatchMixin):
           are served).
         """
         grid = jnp.asarray(self.cfg.alpha_grid, jnp.float32)  # (G,)
-        S = jnp.einsum("knd,kne->kde", z_tr, z_tr)  # (K, d, d)
-        Bt = jnp.einsum("knd,knc->kdc", z_tr, yh_tr)  # (K, d, C)
+        S = jnp.einsum("knd,kne->kde", z_tr, z_tr, precision=FP32)  # (K, d, d)
+        Bt = jnp.einsum("knd,knc->kdc", z_tr, yh_tr, precision=FP32)  # (K, d, C)
         g = grid[:, None, None, None]
-        Lg = jnp.linalg.cholesky(L @ L.T + g * S[None])  # (G, K, d, d)
+        Lg = jnp.linalg.cholesky(fed3r.gram(L.T) + g * S[None])  # (G, K, d, d)
         rhs = b + g * Bt[None]  # (G, K, d, C)
         W = jax.vmap(
             jax.vmap(lambda Lx, rx: jax.scipy.linalg.cho_solve((Lx, True), rx))
@@ -205,13 +205,13 @@ class PersonalizationEngine(DistDispatchMixin):
             if self.cfg.normalize:
                 W = fed3r.normalize_columns(W, axis=2)
             pick = jnp.argmax(
-                jnp.einsum("knd,gkdc->gknc", z_ho, W), axis=-1
+                jnp.einsum("knd,gkdc->gknc", z_ho, W, precision=FP32), axis=-1
             )  # (G, K, N)
             score = jnp.sum(
                 ho[None] * (pick != y[None]).astype(jnp.float32), axis=2
             )  # (G, K)
         else:
-            resid = jnp.einsum("knd,gkdc->gknc", z_ho, W) - yh_ho[None]
+            resid = jnp.einsum("knd,gkdc->gknc", z_ho, W, precision=FP32) - yh_ho[None]
             score = jnp.sum(resid**2, axis=(2, 3))  # (G, K)
         idx = jnp.argmin(score, axis=0)  # (K,) ties → first grid entry
         return grid[idx], jnp.take_along_axis(score, idx[None, :], axis=0)[0]

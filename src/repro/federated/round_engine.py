@@ -165,12 +165,20 @@ class RoundEngine(DistDispatchMixin):
 
     # ---- host API ---------------------------------------------------------
 
+    def _args(self, cohort: PackedCohort):
+        batches = {k: jnp.asarray(v) for k, v in cohort.batches().items()}
+        return batches, jnp.asarray(cohort.client_ids)
+
     def step(self, state: ServerState, cohort: PackedCohort) -> ServerState:
         """Run one round over a packed cohort (ONE jitted dispatch)."""
         with self.dist.telemetry.span("round_step", engine="rounds"):
             self.dist.dispatch()
-            batches = {k: jnp.asarray(v) for k, v in cohort.batches().items()}
-            return self._step(state, batches, jnp.asarray(cohort.client_ids))
+            return self._step(state, *self._args(cohort))
+
+    def lower(self, state: ServerState, cohort: PackedCohort) -> jax.stages.Lowered:
+        """The round program for this cohort shape, lowered without running
+        it: ``.compile().memory_analysis()`` is its device footprint."""
+        return self._step.lower(state, *self._args(cohort))
 
 
 class ReferenceLoop:

@@ -215,10 +215,10 @@ class StreamingEngine(DistDispatchMixin):
             if self._use_kernel():
                 S, dB = fed3r_stats_kernel(z, yh)
             else:
-                S, dB = z.T @ z, z.T @ yh
+                S, dB = fed3r.gram(z), fed3r.gram(z, yh)
             S_local = S
             S, dB, nw = self.dist.all_reduce((S, dB, nw), wire_fn=self._wire_fn())
-            G = state.L @ state.L.T + S
+            G = fed3r.gram(state.L.T) + S
             b = state.b + dB
         elif self.wire.kind != "fp32":
             # compressed uplink, merge backend: the wave's rank-n upload
@@ -229,9 +229,9 @@ class StreamingEngine(DistDispatchMixin):
             if self._use_kernel():
                 S, dB = fed3r_stats_kernel(z, yh)
             else:
-                S, dB = z.T @ z, z.T @ yh
+                S, dB = fed3r.gram(z), fed3r.gram(z, yh)
             G, b = compress.roundtrip_add(
-                state.L @ state.L.T, state.b, S, dB, self.wire, self.cfg.use_kernel
+                fed3r.gram(state.L.T), state.b, S, dB, self.wire, self.cfg.use_kernel
             )
             S_local = S
         elif self._use_kernel():
@@ -239,8 +239,8 @@ class StreamingEngine(DistDispatchMixin):
             b = state.b + dB
             S_local = None
         else:
-            G = state.L @ state.L.T + z.T @ z
-            dB = z.T @ yh
+            G = fed3r.gram(state.L.T) + fed3r.gram(z)
+            dB = fed3r.gram(z, yh)
             b = state.b + dB
             S_local = None
 
@@ -301,7 +301,7 @@ class StreamingEngine(DistDispatchMixin):
         Always refreshes W (a retire is a serving point, not a wave).
         """
         S_A, S_b, S_n = self.dist.all_reduce((A, b, n), wire_fn=self._wire_fn())
-        G = state.L @ state.L.T + S_A
+        G = fed3r.gram(state.L.T) + S_A
         lossy = self.wire if self.wire.kind in ("int8", "fp8") else self._tree_wire
         if lossy is not None:
             L = compress.psd_cholesky(
@@ -339,13 +339,21 @@ class StreamingEngine(DistDispatchMixin):
         """
         with self.dist.telemetry.span("absorb", engine="streaming"):
             self.dist.dispatch()
-            return self._absorb(
-                state,
-                jnp.asarray(packed.inputs),
-                jnp.asarray(packed.labels),
-                jnp.asarray(packed.mask),
-                params,
-            )
+            return self._absorb(state, *self._args(packed), params)
+
+    def lower(
+        self, state: StreamState, packed: PackedArrivals, params: Any = None
+    ) -> jax.stages.Lowered:
+        """The absorb program for this timeline shape, lowered without
+        running it (its compiled text shows which kernels it holds)."""
+        return self._absorb.lower(state, *self._args(packed), params)
+
+    def _args(self, packed: PackedArrivals):
+        # the wave-width axis (dim 1) is the one mesh mode shards
+        return tuple(
+            self.dist.place(a, axis=1)
+            for a in (packed.inputs, packed.labels, packed.mask)
+        )
 
     def absorb_stats(
         self, state: StreamState, A: jax.Array, b: jax.Array, n: jax.Array

@@ -51,10 +51,11 @@ from typing import Any, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core import fed3r
 from repro.federated import compress
 from repro.federated.compress import WireFormat
 from repro.federated.costs import stats_wire_bytes
-from repro.federated.dist import DistConfig, DistContext, donate_argnums
+from repro.federated.dist import DistConfig, DistContext
 from repro.federated.engine import shard_stats
 from repro.federated.telemetry import Telemetry
 from repro.launch.mesh import ICI_BW
@@ -407,7 +408,7 @@ class TieredAbsorber:
         )
         self.telemetry = self.dist.telemetry
         self.cost_model = cost_model
-        donate = donate_argnums(engine.cfg.dist.donate)
+        donate = (0,) if engine.cfg.dist.donate else ()
         self._lower_fn = jax.jit(self._lower_impl)
         self._upper_fn = jax.jit(self._upper_impl, donate_argnums=donate)
         self._blocking_fn = jax.jit(self._blocking_impl, donate_argnums=donate)
@@ -454,7 +455,7 @@ class TieredAbsorber:
                 children, tiers=(top,), use_kernel=self.engine.cfg.use_kernel
             ),
         )
-        G = state.L @ state.L.T + S
+        G = fed3r.gram(state.L.T) + S
         if top.wire.kind in ("int8", "fp8"):
             L = compress.psd_cholesky(G, compress.quant_spectral_bound(S, top.wire))
         else:

@@ -15,7 +15,8 @@ Grid (d/bm, (d+C)/bn, kL + kZ): phase one (k < kL) contracts
 Lᵀ·[Lᵀ | 0], phase two contracts Zᵀ·[Z | Y]; each phase has its own block
 size (BKL for the d-row factor sweep, BKZ for the sample sweep) and
 clamped index maps keep the off-phase operand block loads in range.
-MXU-shaped tiles with fp32 accumulation, as in kernels/fed3r_stats.py.
+MXU-shaped tiles with fp32 accumulation and fp32 contraction of fp32
+operands, as in kernels/fed3r_stats.py.
 
 The BATCHED variant (:func:`batched_chol_gram_pallas`) is the
 personalization engine's hot spot (repro.federated.personalization): one
@@ -38,6 +39,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.fed3r_stats import contract_rows
 
 BM = 128  # rows of the output tile (d dim)
 BN = 128  # cols of the output tile (d+C dim)
@@ -65,17 +68,11 @@ def _chol_gram_kernel(
 
     @pl.when(k < n_k_l)
     def _factor_phase():
-        acc_ref[...] += jax.lax.dot_general(
-            lt_ref[...], ltw_ref[...], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        acc_ref[...] += contract_rows(lt_ref[...], ltw_ref[...])
 
     @pl.when(k >= n_k_l)
     def _arrival_phase():
-        acc_ref[...] += jax.lax.dot_general(
-            z_ref[...], zw_ref[...], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        acc_ref[...] += contract_rows(z_ref[...], zw_ref[...])
 
     @pl.when(k == n_k - 1)
     def _done():
@@ -168,17 +165,11 @@ def _batched_chol_gram_kernel(
 
     @pl.when(k < n_k_l)
     def _factor_phase():
-        acc_ref[...] += jax.lax.dot_general(
-            lt_ref[...], ltw_ref[...], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        acc_ref[...] += contract_rows(lt_ref[...], ltw_ref[...])
 
     @pl.when(k >= n_k_l)
     def _arrival_phase():
-        acc_ref[...] += jax.lax.dot_general(
-            z_ref[0], zw_ref[0], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        acc_ref[...] += contract_rows(z_ref[0], zw_ref[0])
 
     @pl.when(k == n_k - 1)
     def _done():

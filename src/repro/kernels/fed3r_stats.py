@@ -9,9 +9,12 @@ TPU adaptation (vs. the paper's cuBLAS call on A100):
   * grid (d/bm, (d+C)/bn, n/bk): each (i, j) owns one fp32 accumulator tile
     resident in VMEM scratch across the k-sweep — A is up to 12288² fp32
     (576 MB), so tiles must stream; HBM sees each Z tile once per j-pass.
-  * MXU-shaped tiles (128×512×128); bf16 inputs with fp32 accumulation
-    (matching the MXU's native bf16×bf16→fp32 mode) — ridge conditioning
-    needs the fp32 accumulator, not fp32 inputs.
+  * MXU-shaped tiles (128×512×128) with an fp32 accumulator.  fp32
+    operands contract at fp32 (``Precision.HIGHEST``, several MXU passes):
+    Mosaic's default contracts them in ONE bf16 pass, which rounds every
+    feature to 8 mantissa bits — the engines hand this kernel fp32
+    designs and solve a ridge system on the result.  bf16 operands take
+    the native single pass.
 """
 from __future__ import annotations
 
@@ -28,6 +31,18 @@ BN = 128  # cols of the output tile (d+C dim)
 BK = 512  # samples per accumulation step
 
 
+def contract_rows(a: jax.Array, b: jax.Array) -> jax.Array:
+    """aᵀb over the shared leading (row) dim, into fp32 on the MXU.
+
+    fp32 operands contract at fp32; bf16 operands in the native pass."""
+    fp32 = jnp.float32 in (a.dtype, b.dtype)
+    return jax.lax.dot_general(
+        a, b, (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST if fp32 else None,
+        preferred_element_type=jnp.float32,
+    )
+
+
 def _stats_kernel(zt_ref, w_ref, out_ref, acc_ref, *, n_k_steps: int):
     """One (i, j) output tile; grid axis 2 sweeps the n (sample) dim.
 
@@ -42,11 +57,7 @@ def _stats_kernel(zt_ref, w_ref, out_ref, acc_ref, *, n_k_steps: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    z = zt_ref[...]
-    w = w_ref[...]
-    acc_ref[...] += jax.lax.dot_general(
-        z, w, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
+    acc_ref[...] += contract_rows(zt_ref[...], w_ref[...])
 
     @pl.when(k == n_k_steps - 1)
     def _done():

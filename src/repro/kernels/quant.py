@@ -36,9 +36,14 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 TILE = 128  # absmax granularity: one fp32 scale per (TILE, TILE) block
 INT8_QMAX = 127.0  # symmetric int8 range (−127 … 127; −128 unused)
+# The per-tile scale grid lives whole in SMEM and each grid step indexes
+# its own scalar: Mosaic refuses a (1, 1) VMEM block of the (Mt, Nt) grid,
+# whose last two block dims must be multiples of (8, 128) or the full dims.
+_SCALES_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _quantize_kernel(x_ref, q_ref, s_ref):
@@ -46,12 +51,12 @@ def _quantize_kernel(x_ref, q_ref, s_ref):
 
     x_ref: (T, T) fp32 input tile
     q_ref: (T, T) int8 quantized output tile
-    s_ref: (1, 1) fp32 per-tile scale
+    s_ref: (Mt, Nt) fp32 scale grid, whole in SMEM; this step writes [i, j]
     """
     x = x_ref[...]
     absmax = jnp.max(jnp.abs(x))
     scale = jnp.where(absmax > 0.0, absmax / INT8_QMAX, 1.0)
-    s_ref[...] = jnp.reshape(scale, (1, 1))
+    s_ref[pl.program_id(0), pl.program_id(1)] = scale
     q = jnp.clip(jnp.round(x / scale), -INT8_QMAX, INT8_QMAX)
     q_ref[...] = q.astype(jnp.int8)
 
@@ -61,10 +66,11 @@ def _dequant_acc_kernel(acc_ref, q_ref, s_ref, out_ref):
 
     acc_ref: (T, T) fp32 accumulator tile
     q_ref:   (T, T) int8 payload tile
-    s_ref:   (1, 1) fp32 per-tile scale
+    s_ref:   (Mt, Nt) fp32 scale grid, whole in SMEM; this step reads [i, j]
     out_ref: (T, T) fp32 updated accumulator tile
     """
-    out_ref[...] = acc_ref[...] + q_ref[...].astype(jnp.float32) * s_ref[0, 0]
+    scale = s_ref[pl.program_id(0), pl.program_id(1)]
+    out_ref[...] = acc_ref[...] + q_ref[...].astype(jnp.float32) * scale
 
 
 def _pad_to(a: jax.Array, m0: int, m1: int) -> jax.Array:
@@ -93,7 +99,7 @@ def quantize_tiles_pallas(
         in_specs=[pl.BlockSpec((tile, tile), lambda i, j: (i, j))],
         out_specs=[
             pl.BlockSpec((tile, tile), lambda i, j: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, j)),
+            _SCALES_SPEC,
         ],
         out_shape=[
             jax.ShapeDtypeStruct(xp.shape, jnp.int8),
@@ -129,7 +135,7 @@ def dequant_acc_pallas(
         in_specs=[
             pl.BlockSpec((tile, tile), lambda i, j: (i, j)),
             pl.BlockSpec((tile, tile), lambda i, j: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, j)),
+            _SCALES_SPEC,
         ],
         out_specs=pl.BlockSpec((tile, tile), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct(accp.shape, jnp.float32),

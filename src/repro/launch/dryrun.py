@@ -70,7 +70,6 @@ from repro.launch.mesh import (
 )
 from repro.launch.shapes import abstract_params, input_specs, variant_for
 from repro.models import model as model_lib
-from repro.sharding import compat
 from repro.sharding.specs import batch_specs, cache_specs, param_specs, stats_specs
 
 FED3R_N_CLASSES = 2028  # Landmarks-scale classifier head (paper Table 4)
@@ -276,7 +275,7 @@ def lower_one(
         rec["variant"] = f"sliding_window={cfg.sliding_window}"
 
     mesh = mesh or make_production_mesh(multi_pod=multi_pod)
-    compat.set_mesh(mesh)  # ambient mesh: enables model-internal sharding hints
+    jax.set_mesh(mesh)  # ambient mesh: enables model-internal sharding hints
     da = data_axes(mesh)
     ax_sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
     chips = n_chips(mesh)

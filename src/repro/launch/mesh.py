@@ -45,10 +45,20 @@ TIER_BANDWIDTHS = {"ici": ICI_BW, "dcn": DCN_BW, "wan": WAN_BW}
 _TIER_AXIS_NAMES = ("cloud", "region", "edge")
 
 
+def _make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with every axis Auto: the compiler propagates
+    shardings and :func:`repro.sharding.hints.hint` constrains them.  (JAX's
+    default, Explicit, turns each hint into an assertion that the array is
+    already sharded that way.)"""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
+
+
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _make_mesh(shape, axes)
 
 
 def make_host_mesh(model_parallel: int = 1, *, pods: int = 1) -> jax.sharding.Mesh:
@@ -74,10 +84,8 @@ def make_host_mesh(model_parallel: int = 1, *, pods: int = 1) -> jax.sharding.Me
         )
     data = n // (model_parallel * pods)
     if pods > 1:
-        return jax.make_mesh(
-            (pods, data, model_parallel), ("pod", "data", "model")
-        )
-    return jax.make_mesh((data, model_parallel), ("data", "model"))
+        return _make_mesh((pods, data, model_parallel), ("pod", "data", "model"))
+    return _make_mesh((data, model_parallel), ("data", "model"))
 
 
 def make_tier_host_mesh(
@@ -120,7 +128,7 @@ def make_tier_host_mesh(
             f"{n} devices do not factor as tiers {tier_shape} × "
             f"model_parallel={model_parallel}"
         )
-    return jax.make_mesh(
+    return _make_mesh(
         tuple(tier_shape) + (model_parallel,), tuple(tier_names) + ("model",)
     )
 

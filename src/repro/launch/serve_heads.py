@@ -54,6 +54,7 @@ from repro.federated.personalization import (
 )
 from repro.federated.streaming_engine import StreamConfig, StreamingEngine
 from repro.federated.telemetry import get_telemetry
+from repro.launch.compile_cache import enable_compile_cache
 
 
 class HeadCache:
@@ -482,6 +483,7 @@ def main() -> None:
     ap.add_argument("--zipf-exponent", type=float, default=1.1)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
     serve_heads(
         n_waves=args.waves, segment=args.segment, rate=args.rate,
         queries_per_burst=args.queries, bursts_per_segment=args.bursts,

@@ -58,6 +58,7 @@ from repro.federated.arrivals import (
 )
 from repro.federated.streaming_engine import StreamConfig, StreamingEngine
 from repro.federated.telemetry import get_telemetry
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def serve_stream(
@@ -321,6 +322,7 @@ def main() -> None:
                          "or chaos-injected async merge-on-arrival rounds")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
     serve_stream(
         n_waves=args.waves, rate=args.rate, policy=args.policy, k=args.k,
         segment=args.segment, skew=args.skew, n_clients=args.clients,

@@ -62,7 +62,6 @@ import numpy as np
 from repro.core import fed3r
 from repro.core.fed3r import Fed3RFactored
 from repro.data.pipeline import pack_personal_cohort
-from repro.federated.dist import donate_argnums
 from repro.federated.personalization import (
     PersonalizationEngine,
     PersonalizeConfig,
@@ -193,10 +192,10 @@ class ServingEngine:
         self._latency_hist = t.histogram("serving_latency_seconds", inst=inst)
         self.stage_s = {"absorb": 0.0, "solve": 0.0, "serve": 0.0}
         self._solve = jax.jit(
-            self._solve_impl, donate_argnums=donate_argnums(True, (0,))
+            self._solve_impl, donate_argnums=(0,)
         )
         self._refresh_global = jax.jit(
-            self._refresh_global_impl, donate_argnums=donate_argnums(True, (0,))
+            self._refresh_global_impl, donate_argnums=(0,)
         )
         self._serve = jax.jit(self._serve_impl)
 
@@ -248,7 +247,7 @@ class ServingEngine:
         """ONE dispatch answers the whole in-flight batch: gather each
         query's resident head row and contract — O(1) dispatches in the
         tenant count."""
-        return jnp.einsum("qd,qdc->qc", xs, heads[slot_idx])
+        return jnp.einsum("qd,qdc->qc", xs, heads[slot_idx], precision=fed3r.FP32)
 
     # ---- host API ---------------------------------------------------------
 
@@ -272,6 +271,45 @@ class ServingEngine:
             t = int(t)
             self.tenant_versions[t] = self.tenant_versions.get(t, 0) + 1
         return trace
+
+    def _solve_args(self, solved: Sequence[Tuple[int, int]]) -> tuple:
+        """The solve program's arguments for ``(tenant, slot)`` pairs."""
+        slot_map = dict(solved)
+        clients = []
+        for t, _ in solved:
+            cd = self.dataset.client(t)
+            clients.append((np.asarray(cd.features), np.asarray(cd.labels)))
+        pad = self.cfg.solve_bucket
+        packed = pack_personal_cohort(
+            clients,
+            client_ids=[t for t, _ in solved],
+            cohort_size=-(-len(solved) // pad) * pad,
+            max_n=self.max_n,
+        )
+        # cohort rows are canonically sorted; padded rows get an
+        # out-of-range index so the scatter drops them
+        slot_vec = np.asarray(
+            [slot_map.get(int(c), self.table.n_slots) for c in packed.client_ids],
+            np.int32,
+        )
+        return (
+            self.table.heads,
+            self.state.L,
+            self.state.b,
+            jnp.asarray(packed.inputs),
+            jnp.asarray(packed.labels),
+            jnp.asarray(packed.mask),
+            jnp.asarray(packed.holdout),
+            jnp.asarray(slot_vec),
+        )
+
+    def lower_solve(self, tenants: Sequence[int]) -> jax.stages.Lowered:
+        """The solve program for a miss cohort of these tenants, lowered
+        without running it (its compiled text shows which kernels it holds).
+        Every row gets the out-of-range slot, as a padded row would."""
+        return self._solve.lower(
+            *self._solve_args([(t, self.table.n_slots) for t in tenants])
+        )
 
     def _has_data(self, tenant: int) -> bool:
         return 0 <= tenant < self.dataset.n_clients
@@ -387,35 +425,7 @@ class ServingEngine:
         span = self.telemetry.span("solve", engine="serving")
         span.__enter__()
         if solved:
-            slot_map = {t: s for t, s in solved}
-            clients = []
-            for t, _ in solved:
-                cd = self.dataset.client(t)
-                clients.append((np.asarray(cd.features), np.asarray(cd.labels)))
-            pad = self.cfg.solve_bucket
-            packed = pack_personal_cohort(
-                clients,
-                client_ids=[t for t, _ in solved],
-                cohort_size=-(-len(solved) // pad) * pad,
-                max_n=self.max_n,
-            )
-            # cohort rows are canonically sorted; padded rows get an
-            # out-of-range index so the scatter drops them
-            slot_vec = np.asarray(
-                [slot_map.get(int(c), self.table.n_slots)
-                 for c in packed.client_ids],
-                np.int32,
-            )
-            self.table.heads, _ = self._solve(
-                self.table.heads,
-                self.state.L,
-                self.state.b,
-                jnp.asarray(packed.inputs),
-                jnp.asarray(packed.labels),
-                jnp.asarray(packed.mask),
-                jnp.asarray(packed.holdout),
-                jnp.asarray(slot_vec),
-            )
+            self.table.heads, _ = self._solve(*self._solve_args(solved))
             self.solve_dispatches += 1
             self.table.assign(
                 [s for _, s in solved],

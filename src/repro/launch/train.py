@@ -16,8 +16,9 @@ optimizer buffers, round index — checkpoints every eval; ``--resume``
 continues from the latest snapshot and reproduces the uninterrupted run
 (cohorts and shuffles are pure functions of the round index).
 
-On this CPU container the driver runs reduced configs on the host mesh;
-on TPU the same code takes ``--mesh pod|multipod``.
+The trainer runs over the host mesh of whatever devices exist: reduced
+configs on CPU, the full-width ``fed3r-mnv2-proxy`` on one TPU chip
+(``chip_smoke.py`` drives it there).
 
 Usage:
   PYTHONPATH=src python -m repro.launch.train --arch fed3r-mnv2-proxy-smoke \
@@ -44,10 +45,10 @@ from repro.federated.algorithms import make_algorithm, server_state_from_tree
 from repro.federated.engine import AccumulationEngine, EngineConfig
 from repro.federated.round_engine import RoundConfig, RoundEngine
 from repro.federated.sampling import sample_round
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import make_cls_per_example_loss
 from repro.models import build_model
-from repro.sharding import compat
 
 _FT_SEED = 3  # phase-2 sampling/shuffle seed (pure function of the round)
 
@@ -73,7 +74,7 @@ def run(
     cfg = get_config(arch)
     model = build_model(cfg)
     mesh = make_host_mesh()
-    compat.set_mesh(mesh)
+    jax.set_mesh(mesh)
 
     rng = jax.random.PRNGKey(0)
     params = model.init(rng)
@@ -86,7 +87,7 @@ def run(
     test_tokens, test_labels = ds.tokens[:n_test], ds.labels[:n_test]
     tokens_np, labels_np = np.asarray(ds.tokens), np.asarray(ds.labels)
 
-    log = {"fed3r_acc": None, "ft_acc": [], "rounds": []}
+    log = {"fed3r_acc": None, "ft_acc": [], "rounds": [], "ft_step_memory": None}
 
     # Resuming from a full-state snapshot makes phase 1 dead work: the
     # loaded ServerState overwrites whatever head it would produce.
@@ -169,6 +170,16 @@ def run(
             [(tokens_np[parts[int(k)]], labels_np[parts[int(k)]]) for k in chosen],
             local_batch_size, n_batches, client_ids=chosen, seed=(_FT_SEED, rnd),
         )
+        if rnd == start_round:
+            # the compiled round program's device footprint (argument,
+            # output, temp bytes), read before its first run
+            mem = round_engine.lower(state, cohort).compile().memory_analysis()
+            log["ft_step_memory"] = mem
+            if verbose:
+                print(f"[ft:{ft_strategy}] round program of {clients_per_round} "
+                      f"clients: argument {mem.argument_size_in_bytes} B, "
+                      f"output {mem.output_size_in_bytes} B, alias "
+                      f"{mem.alias_size_in_bytes} B, temp {mem.temp_size_in_bytes} B")
         state = round_engine.step(state, cohort)  # ONE dispatch per round
         if (rnd + 1) % 5 == 0 or rnd == rounds - 1:
             acc = float(evaluate(state.params))
@@ -198,6 +209,7 @@ def main() -> None:
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--resume", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
     run(
         args.arch, rounds=args.rounds, n_clients=args.clients,
         clients_per_round=args.per_round, seq_len=args.seq_len,

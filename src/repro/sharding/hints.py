@@ -9,17 +9,24 @@ MoE dispatch buffers).  ``hint(x, *tokens)`` places an explicit
     "model"  -> sharded over the tensor-parallel axis when divisible
     None     -> unconstrained... replicated along that dim
 
-Hints resolve against the *ambient* abstract mesh (``jax.set_mesh``, via the
-version-compat layer in :mod:`repro.sharding.compat`); when no mesh is set
-(unit tests, the CPU simulator) they are exact no-ops, so model code stays
-mesh-agnostic.
+Hints resolve against the *ambient* abstract mesh (``jax.set_mesh``); when
+no mesh is set (unit tests, the CPU simulator) they are exact no-ops, so
+model code stays mesh-agnostic.
 """
 from __future__ import annotations
 
 import jax
 from jax.sharding import PartitionSpec as P
 
-from repro.sharding import compat
+
+def _partitioned_axes() -> dict:
+    """{axis name: size} of the ambient mesh's axes that the compiler still
+    partitions; empty when no mesh is set.  Inside ``shard_map`` the mapped
+    axes are Manual — the code there already sees its local shard — so they
+    are left out, and a hint over them is an exact no-op."""
+    mesh = jax.sharding.get_abstract_mesh()
+    manual = set(mesh.manual_axes)
+    return {a: n for a, n in mesh.shape.items() if a not in manual}
 
 
 def _resolve(shape, tokens, axis_names, axis_sizes):
@@ -52,11 +59,8 @@ def _resolve(shape, tokens, axis_names, axis_sizes):
 
 def data_shards() -> int:
     """Product of the non-"model" (batch-carrying) mesh axis sizes; 1 if none."""
-    mesh = compat.get_abstract_mesh()
-    if mesh is None:
-        return 1
     s = 1
-    for name, size in compat.axis_sizes(mesh).items():
+    for name, size in _partitioned_axes().items():
         if name != "model":
             s *= size
     return s
@@ -64,19 +68,15 @@ def data_shards() -> int:
 
 def mesh_axis_size(name: str) -> int:
     """Size of an ambient-mesh axis (1 when no mesh is set)."""
-    mesh = compat.get_abstract_mesh()
-    if mesh is None:
-        return 1
-    return compat.axis_sizes(mesh).get(name, 1)
+    return _partitioned_axes().get(name, 1)
 
 
 def hint(x: jax.Array, *tokens) -> jax.Array:
     """Constrain ``x``'s sharding by logical dim tokens; no-op without mesh."""
-    mesh = compat.get_abstract_mesh()
-    if mesh is None:
+    axis_sizes = _partitioned_axes()
+    if not axis_sizes:
         return x
-    axis_sizes = compat.axis_sizes(mesh)
-    spec = _resolve(x.shape, tokens, tuple(mesh.axis_names), axis_sizes)
+    spec = _resolve(x.shape, tokens, tuple(axis_sizes), axis_sizes)
     if all(entry is None for entry in spec):
         return x  # fully replicated constraint ⇒ exact no-op
-    return jax.lax.with_sharding_constraint(x, compat.sharding_for(mesh, spec))
+    return jax.lax.with_sharding_constraint(x, spec)

@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import fed3r
 from repro.data.pipeline import (
@@ -158,9 +159,6 @@ def test_dist_context_merge_all_reduce_is_identity():
 
 @needs4
 def test_two_stage_psum_equals_flat_psum_on_pod_mesh():
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as P
-
     mesh = make_host_mesh(pods=2)
     dp = data_parallel_size(mesh)
     x = jnp.asarray(
@@ -175,8 +173,8 @@ def test_two_stage_psum_equals_flat_psum_on_pod_mesh():
         return jax.lax.psum(v, ("pod", "data"))
 
     spec = P(("pod", "data"))
-    a = shard_map(two_stage, mesh=mesh, in_specs=spec, out_specs=P())(x)
-    b = shard_map(flat, mesh=mesh, in_specs=spec, out_specs=P())(x)
+    a = jax.shard_map(two_stage, mesh=mesh, in_specs=spec, out_specs=P())(x)
+    b = jax.shard_map(flat, mesh=mesh, in_specs=spec, out_specs=P())(x)
     # exact grid values: any reduction order is bit-identical
     assert np.array_equal(np.asarray(a), np.asarray(b))
     np.testing.assert_allclose(
@@ -439,3 +437,18 @@ def test_streaming_sharded_on_pod_mesh():
     assert eng.dispatches == 1
     assert np.array_equal(np.asarray(ref.L), np.asarray(got.L))
     assert np.array_equal(np.asarray(ref.W), np.asarray(got.W))
+
+
+def test_place_puts_batches_straight_into_the_data_sharding():
+    """Mesh mode hands each device only its shard of a packed batch,
+    instead of staging the whole array on device 0 first."""
+    mesh = make_host_mesh()
+    dp = data_parallel_size(mesh)
+    ctx = DistContext(DistConfig(aggregation="psum", mesh=mesh))
+    x = np.arange(3 * 2 * dp * 5, dtype=np.float32).reshape(3, 2 * dp, 5)
+    placed = ctx.place(x, axis=1)
+    assert placed.sharding == NamedSharding(mesh, P(None, "data"))
+    assert {s.data.shape for s in placed.addressable_shards} == {(3, 2, 5)}
+    np.testing.assert_array_equal(np.asarray(placed), x)
+    merged = DistContext(DistConfig()).place(x)
+    assert merged.sharding.is_fully_replicated

@@ -225,7 +225,6 @@ def test_aggregate_merge_is_identity_and_psum_validates():
 
 
 def test_psum_backend_matches_merge_on_host_mesh(rng):
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     n_dev = len(jax.devices())
@@ -237,7 +236,7 @@ def test_psum_backend_matches_merge_on_host_mesh(rng):
     def local(f, l):
         return aggregate(shard_stats(f, l, C, use_kernel=False), "psum", ("data",))
 
-    agg = shard_map(local, mesh=mesh, in_specs=(P("data", None), P("data")),
+    agg = jax.shard_map(local, mesh=mesh, in_specs=(P("data", None), P("data")),
                     out_specs=P())(feats, labels)
     ref = fed3r.client_stats(feats, labels, C)
     np.testing.assert_allclose(np.asarray(agg.A), np.asarray(ref.A),

@@ -71,8 +71,11 @@ def test_fed3r_resampled_client_sends_exactly_once(fed_data):
     W, stats, hist = run_fed3r(fed, test.features, test.labels, f3, cfg)
     assert hist.clients_seen[-1] == N_CLIENTS  # coupon collector finished
     cen = fed3r.client_stats(jnp.asarray(fed.features), jnp.asarray(fed.labels), C)
-    np.testing.assert_allclose(np.asarray(stats.A), np.asarray(cen.A),
-                               rtol=1e-4, atol=1e-4)
+    # a lost or doubled client moves entries by O(max|A|); fp32 summation
+    # order across the two folds moves them by O(eps·max|A|)
+    A_cen = np.asarray(cen.A)
+    np.testing.assert_allclose(np.asarray(stats.A), A_cen,
+                               rtol=1e-4, atol=1e-6 * np.abs(A_cen).max())
     assert float(stats.n) == len(fed.labels)
 
 

@@ -95,3 +95,26 @@ def test_command_r_heads_shard_on_model():
     specs = param_specs(cfg, params, AX)
     wq = tuple(specs["layers"]["attn"]["wq"])
     assert wq[2] == "model"
+
+
+def test_hints_leave_shard_map_axes_alone():
+    """Inside shard_map the mapped axes are Manual: the body already sees
+    its local shard, so a hint must not constrain over them (the engines'
+    dist-owned mesh mode runs ``hint`` inside shard_map)."""
+    import jax.numpy as jnp
+
+    from repro.sharding import hints
+
+    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    seen = []
+
+    def body(x):
+        seen.append(hints._partitioned_axes())
+        return hints.hint(x, "batch")
+
+    with jax.set_mesh(mesh):
+        assert hints._partitioned_axes() == {"data": 1, "model": 1}
+        jax.shard_map(body, mesh=mesh, in_specs=P("data"), out_specs=P("data"))(
+            jnp.ones((4, 2))
+        )
+    assert seen == [{}]

@@ -77,7 +77,6 @@ def test_fed3r_stats_step_matches_simulator_path(rng):
 def test_fed3r_psum_aggregation_on_host_mesh(rng):
     """The datacenter aggregation (psum over data) == simulator merge."""
     from repro.core.fed3r import aggregate_mesh
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     n_dev = len(jax.devices())
@@ -90,7 +89,7 @@ def test_fed3r_psum_aggregation_on_host_mesh(rng):
         s = fed3r.client_stats(f, l, C)
         return aggregate_mesh(s, ("data",))
 
-    agg = shard_map(
+    agg = jax.shard_map(
         local_stats, mesh=mesh,
         in_specs=(P("data", None), P("data")),
         out_specs=P(),
