@@ -180,7 +180,8 @@ class AccumulationEngine(DistDispatchMixin):
         )
 
     def init(self, d: int) -> EngineStats:
-        return engine_init(d, self.cfg.n_classes)
+        with self.dist.telemetry.span("init", engine="accumulation"):
+            return engine_init(d, self.cfg.n_classes)
 
     # ---- jitted core ------------------------------------------------------
 
@@ -249,9 +250,13 @@ class AccumulationEngine(DistDispatchMixin):
         self, acc: EngineStats, packed: PackedClients, params: Any = None
     ) -> EngineStats:
         """Fold a packed client selection into the accumulator (one dispatch)."""
-        with self.dist.telemetry.span("accumulate", engine="accumulation"):
+        span = self.dist.telemetry.span
+        with span("accumulate", engine="accumulation"):
             self.dist.dispatch()
-            return self._accumulate(acc, *self._args(packed), params)
+            with span("place", engine="accumulation"):
+                args = self._args(packed)
+            with span("launch", engine="accumulation"):
+                return self._accumulate(acc, *args, params)
 
     def lower(
         self, acc: EngineStats, packed: PackedClients, params: Any = None

@@ -159,16 +159,17 @@ class StreamingEngine(DistDispatchMixin):
         self._absorb_stats = jax.jit(self._absorb_stats_impl)
 
     def init(self, d: int) -> StreamState:
-        fac = fed3r.init_factored(d, self.cfg.n_classes, self.cfg.ridge_lambda)
-        return StreamState(
-            L=fac.L,
-            b=fac.b,
-            n=jnp.zeros((), jnp.float32),
-            W=jnp.zeros((d, self.cfg.n_classes), jnp.float32),
-            wave=jnp.zeros((), jnp.int32),
-            stale_waves=jnp.zeros((), jnp.int32),
-            stale_samples=jnp.zeros((), jnp.float32),
-        )
+        with self.dist.telemetry.span("init", engine="streaming"):
+            fac = fed3r.init_factored(d, self.cfg.n_classes, self.cfg.ridge_lambda)
+            return StreamState(
+                L=fac.L,
+                b=fac.b,
+                n=jnp.zeros((), jnp.float32),
+                W=jnp.zeros((d, self.cfg.n_classes), jnp.float32),
+                wave=jnp.zeros((), jnp.int32),
+                stale_waves=jnp.zeros((), jnp.int32),
+                stale_samples=jnp.zeros((), jnp.float32),
+            )
 
     # ---- pure core (also usable directly inside shard_map) ----------------
 
@@ -337,9 +338,13 @@ class StreamingEngine(DistDispatchMixin):
         Returns the advanced state (the served classifier is ``state.W``)
         and the per-wave :class:`WaveTrace`.
         """
-        with self.dist.telemetry.span("absorb", engine="streaming"):
+        span = self.dist.telemetry.span
+        with span("absorb", engine="streaming"):
             self.dist.dispatch()
-            return self._absorb(state, *self._args(packed), params)
+            with span("place", engine="streaming"):
+                args = self._args(packed)
+            with span("launch", engine="streaming"):
+                return self._absorb(state, *args, params)
 
     def lower(
         self, state: StreamState, packed: PackedArrivals, params: Any = None

@@ -18,10 +18,19 @@ through:
   reported quantile (the geometric bucket midpoint) is within half a
   bucket (≤ ~4.4% relative) of the true order statistic.
 * **Spans** — nestable ``with telemetry.span("solve", engine="serving")``
-  context managers on the *monotonic* ``time.perf_counter`` clock (the
-  wall clock steps backwards under NTP).  Each exit records the stage
-  duration into a ``span_seconds`` histogram labeled with the
-  ``/``-joined stage path, so per-stage p50/p99 fall out for free.
+  context managers.  Each exit records the stage's wall time, on the
+  *monotonic* ``time.perf_counter`` clock (the wall clock steps backwards
+  under NTP), into ``span_seconds`` and the calling thread's CPU time
+  (``time.thread_time_ns``) into ``span_cpu_seconds``, both labeled with
+  the ``/``-joined stage path; wall minus CPU is what the caller waited
+  for (the runtime's in-flight limit, the GIL, allocation).  While a
+  ``jax.profiler`` trace runs, a span is also a ``TraceAnnotation`` named
+  ``fed3r:<stage path>`` (labels as its metadata), on the same timeline
+  as the device's ops, and it lands in :attr:`Telemetry.span_log`, a
+  bounded ring of ``(stage, start_ns, end_ns, cpu_ns)`` whose start is on
+  the host clock the profiler stamps its events with (``time.time_ns``;
+  a trace file holds its events relative to its session's start, one
+  offset away).
 * **Flight recorder** — a bounded ring (``collections.deque(maxlen=...)``)
   of structured events: client demoted/readmitted, request shed by
   overflow/deadline, staleness drop, chaos fault injected, fp8 fallback,
@@ -40,11 +49,11 @@ an explicit ``telemetry=`` handle.
 
 Hard contracts:
 
-* **Zero device dispatches.**  Nothing in this module touches jax on a
-  metric path — counters are integer adds, spans are two
-  ``perf_counter`` calls, events are deque appends.  The only jax import
-  is lazy, inside the optional :meth:`Telemetry.trace_window` profiler
-  capture, which is inert unless ``profile_dir`` is set.
+* **Zero device dispatches.**  Nothing in this module dispatches to a
+  device — counters are integer adds, spans read clocks and open a
+  profiler annotation (a host event, recorded only while a trace runs),
+  events are deque appends.  The profiler is imported lazily, on a
+  span's first use of its stage, so the module holds no jax.
 * **Near-free when disabled.**  ``Telemetry(enabled=False)`` turns
   spans into a shared no-op context manager and events/histogram
   convenience paths into early returns.  Counters still count — the
@@ -57,16 +66,23 @@ import math
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
-from typing import Any, Dict, Iterator, List, Optional, Tuple
+from typing import Any, Dict, List, Tuple
 
 # Log-bucket geometry: 8 buckets per octave → edge ratio 2^(1/8) ≈ 1.0905.
 # ~372 buckets cover 1 ns .. 10^5 s, so memory is bounded regardless of
 # sample count and a bucket midpoint is within ~4.4% of any sample in it.
 _BUCKETS_PER_OCTAVE = 8
 _LOG_BASE = math.log(2.0) / _BUCKETS_PER_OCTAVE
+_log, _floor = math.log, math.floor  # bound once: observe runs on every span exit
 
 _LabelKey = Tuple[Tuple[str, str], ...]
+
+# A span's profiler annotation is named SPAN_PREFIX + its stage path; no
+# event of the runtime or of a benchmark carries the prefix.
+SPAN_PREFIX = "fed3r:"
+# Traced spans the span log keeps.  An engine call opens three (the call,
+# its placement, its launch); at about 600 calls a second, over 30 s of trace.
+SPAN_LOG = 1 << 16
 
 
 def _label_key(labels: Dict[str, Any]) -> _LabelKey:
@@ -144,8 +160,9 @@ class Histogram:
         if v > self.max:
             self.max = v
         if v > 0.0:
-            idx = math.floor(math.log(v) / _LOG_BASE)
-            self.counts[idx] = self.counts.get(idx, 0) + 1
+            idx = _floor(_log(v) / _LOG_BASE)
+            counts = self.counts
+            counts[idx] = counts.get(idx, 0) + 1
         else:
             self.zero_count += 1
 
@@ -201,13 +218,16 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """One live span: perf_counter at enter, histogram observe at exit.
+    """One live span: clocks, and a profiler annotation while a trace runs,
+    at enter; at exit the stage's wall and CPU time into its histograms and,
+    where it was annotated, its record into the span log.
 
     Nesting composes the stage path (``tick/solve``) from the per-thread
     span stack, so nested stages get their own histogram series.
     """
 
-    __slots__ = ("_t", "_name", "_labels", "_path", "_t0")
+    __slots__ = ("_t", "_name", "_labels", "_stack", "_path", "_cells", "_note",
+                 "_w0", "_t0", "_c0")
 
     def __init__(self, t: "Telemetry", name: str, labels: Dict[str, Any]):
         self._t = t
@@ -215,18 +235,38 @@ class _Span:
         self._labels = labels
 
     def __enter__(self) -> "_Span":
-        stack = self._t._span_stack
-        self._path = f"{stack[-1]}/{self._name}" if stack else self._name
-        stack.append(self._path)
-        self._t0 = time.perf_counter()
+        t = self._t
+        self._stack = stack = t._span_stack
+        self._path = path = f"{stack[-1]}/{self._name}" if stack else self._name
+        stack.append(path)
+        cells = t._span_cache.get((path, tuple(self._labels.items())))
+        if cells is None:
+            cells = t._span_cells(path, self._labels)
+        self._cells = cells
+        annotation = cells[3]
+        if annotation.is_enabled():  # a profiler trace is running
+            self._note = note = annotation(cells[2], **self._labels)
+            note.__enter__()
+            self._w0 = time.time_ns()
+        else:
+            self._note = None
+        self._t0 = time.perf_counter_ns()
+        self._c0 = time.thread_time_ns()  # inside the wall interval: CPU ≤ wall
         return self
 
     def __exit__(self, *exc: Any) -> bool:
-        dt = time.perf_counter() - self._t0
-        stack = self._t._span_stack
+        cpu = time.thread_time_ns() - self._c0
+        wall = time.perf_counter_ns() - self._t0
+        stack = self._stack
         if stack and stack[-1] == self._path:
             stack.pop()
-        self._t.histogram("span_seconds", stage=self._path, **self._labels).observe(dt)
+        cells = self._cells
+        cells[0].observe(wall * 1e-9)
+        cells[1].observe(cpu * 1e-9)
+        note = self._note
+        if note is not None:
+            note.__exit__(None, None, None)
+            self._t.span_log.append((self._path, self._w0, self._w0 + wall, cpu))
         return False
 
 
@@ -235,20 +275,18 @@ class Telemetry:
     flight-recorder event ring.  Process-global by default
     (:func:`get_telemetry`) but plain to construct and inject."""
 
-    def __init__(
-        self,
-        *,
-        enabled: bool = True,
-        ring: int = 4096,
-        profile_dir: Optional[str] = None,
-    ):
+    def __init__(self, *, enabled: bool = True, ring: int = 4096):
         self.enabled = enabled
-        # jax.profiler trace-window target; None keeps trace_window a no-op
-        self.profile_dir = profile_dir
         self._lock = threading.Lock()
         self._counters: Dict[Tuple[str, _LabelKey], Counter] = {}
         self._gauges: Dict[Tuple[str, _LabelKey], Gauge] = {}
         self._hists: Dict[Tuple[str, _LabelKey], Histogram] = {}
+        # (stage path, labels as given) → _span_cells: an exit observes
+        # into cells it already holds
+        self._span_cache: Dict[Tuple[str, Tuple], Tuple] = {}
+        # spans that finished while a profiler trace ran, newest last:
+        # (stage, start_ns, end_ns, cpu_ns), start on time.time_ns
+        self.span_log: deque = deque(maxlen=SPAN_LOG)
         self._instances: Dict[str, int] = {}
         self._local = threading.local()
         self.events: deque = deque(maxlen=int(ring))
@@ -300,16 +338,30 @@ class Telemetry:
 
     @property
     def _span_stack(self) -> List[str]:
-        stack = getattr(self._local, "stack", None)
-        if stack is None:
+        try:
+            return self._local.stack
+        except AttributeError:  # this thread's first span
             stack = self._local.stack = []
-        return stack
+            return stack
 
     def span(self, name: str, **labels: Any):
-        """Per-stage monotonic-clock span; a shared no-op when disabled."""
+        """Per-stage span (wall and CPU time, a profiler annotation, a
+        span-log record); a shared no-op when disabled."""
         if not self.enabled:
             return _NULL_SPAN
         return _Span(self, name, labels)
+
+    def _span_cells(self, path: str, labels: Dict[str, Any]) -> Tuple:
+        """A stage's cached ``(span_seconds cell, span_cpu_seconds cell,
+        annotation name, annotation class)``."""
+        from jax.profiler import TraceAnnotation  # lazy: a host event, no dispatch
+
+        return self._span_cache.setdefault((path, tuple(labels.items())), (
+            self.histogram("span_seconds", stage=path, **labels),
+            self.histogram("span_cpu_seconds", stage=path, **labels),
+            SPAN_PREFIX + path,
+            TraceAnnotation,
+        ))
 
     # ---- flight recorder ------------------------------------------------
 
@@ -328,24 +380,6 @@ class Telemetry:
     def events_jsonl(self) -> str:
         """The event ring as JSON-lines (one event per line)."""
         return "\n".join(json.dumps(ev, sort_keys=True) for ev in self.events)
-
-    # ---- optional profiler window --------------------------------------
-
-    @contextmanager
-    def trace_window(self, label: str = "trace") -> Iterator[None]:
-        """Optional ``jax.profiler`` capture around a code window.
-
-        Inert (and jax-import-free) unless the registry is enabled AND
-        ``profile_dir`` is set — the flag-gated escape hatch for on-device
-        stage attribution; host metrics never need it.
-        """
-        if not (self.enabled and self.profile_dir):
-            yield
-            return
-        import jax  # lazy: the only jax touch in this module
-
-        with jax.profiler.trace(self.profile_dir):
-            yield
 
     # ---- exposition -----------------------------------------------------
 
@@ -468,6 +502,7 @@ class Telemetry:
         self.events.clear()
         self.events_dropped = 0
         self._seq = 0
+        self.span_log.clear()
 
 
 def _escape_label(v: str) -> str:

@@ -203,12 +203,80 @@ def test_span_paths_nest():
     with t.span("retire", engine="async"):
         with t.span("fold", engine="async"):
             pass
-    stages = {
-        h["labels"]["stage"]
-        for h in t.snapshot()["histograms"]
-        if h["name"] == "span_seconds"
-    }
-    assert stages == {"retire", "retire/fold"}
+    for name in ("span_seconds", "span_cpu_seconds"):
+        stages = {
+            h["labels"]["stage"]
+            for h in t.snapshot()["histograms"]
+            if h["name"] == name
+        }
+        assert stages == {"retire", "retire/fold"}, name
+    assert len(t.span_log) == 0  # the log keeps traced spans only
+
+
+def test_span_records_cpu_time_within_its_wall_time():
+    t = Telemetry()
+    with t.span("spin"):
+        t0 = time.perf_counter()
+        while time.perf_counter() - t0 < 0.05:
+            pass
+    with t.span("sleep"):
+        time.sleep(0.05)
+    # one tick of the thread CPU clock: what CPU may exceed wall by
+    tick = time.get_clock_info("thread_time").resolution
+    for stage in ("spin", "sleep"):
+        wall = t.histogram("span_seconds", stage=stage)
+        cpu = t.histogram("span_cpu_seconds", stage=stage)
+        assert wall.count == cpu.count == 1
+        assert cpu.sum <= wall.sum + tick, stage
+    # the sleeping thread waited; the spinning one ran (for as long as a
+    # busy machine let it)
+    sleep_cpu = t.histogram("span_cpu_seconds", stage="sleep").sum
+    assert sleep_cpu < 0.5 * t.histogram("span_seconds", stage="sleep").sum
+    assert t.histogram("span_cpu_seconds", stage="spin").sum > sleep_cpu
+    # the exit observes into the cells the registry exposes, reset included
+    t.reset()
+    with t.span("spin"):
+        pass
+    assert t.histogram("span_cpu_seconds", stage="spin").count == 1
+
+
+def _trace_events(directory):
+    from jax.profiler import ProfileData
+
+    (path,) = directory.glob("**/*.xplane.pb")
+    data = ProfileData.from_file(str(path))
+    return [
+        (ev.name, float(ev.start_ns), float(ev.start_ns + ev.duration_ns), dict(ev.stats))
+        for plane in data.planes if plane.name.startswith("/host:")
+        for line in plane.lines for ev in line.events
+    ]
+
+
+def test_span_reaches_the_profiler_trace_and_the_span_log(tmp_path):
+    t, off = Telemetry(), Telemetry(enabled=False)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with t.span("retire", engine="async"):
+            with t.span("fold", engine="async"):
+                time.sleep(0.002)
+            with off.span("hidden"):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    events = {name: (a, b, stats) for name, a, b, stats in _trace_events(tmp_path)
+              if name.startswith("fed3r:")}
+    assert set(events) == {"fed3r:retire", "fed3r:retire/fold"}  # none for "hidden"
+    (pa, pb, pstats), (ca, cb, _) = events["fed3r:retire"], events["fed3r:retire/fold"]
+    assert pa <= ca < cb <= pb  # the child lies inside its parent
+    assert cb - ca >= 2e6
+    assert pstats.get("engine") == "async"  # labels ride as metadata
+    # the log holds the traced spans, innermost first, on the host clock,
+    # each inside its own annotation
+    assert [r[0] for r in t.span_log] == ["retire/fold", "retire"]
+    (_, cs, ce, ccpu), (_, ps, pe, _) = t.span_log
+    assert ps <= cs < ce <= pe and 0 <= ccpu <= ce - cs
+    assert 2e6 <= ce - cs <= cb - ca and pe - ps <= pb - pa
+    assert len(off.span_log) == 0
 
 
 # ---------------------------------------------------------------------------
@@ -277,6 +345,30 @@ def test_all_four_engines_dispatch_counter_equals_legacy(registry):
         if h["name"] == "span_seconds"
     }
     assert {"accumulation", "streaming", "rounds", "personalization"} <= engines
+
+
+@pytest.mark.parametrize("kind", ["accumulation", "streaming"])
+def test_engine_spans_split_placement_from_launch(registry, kind):
+    if kind == "accumulation":
+        eng = AccumulationEngine(EngineConfig(n_classes=C))
+        call = "accumulate"
+        out = eng.accumulate(eng.init(D), pack_client_shards(_clients(1, [8, 6]), 2, max_n=8))
+    else:
+        eng = StreamingEngine(StreamConfig(n_classes=C, ridge_lambda=LAM))
+        call = "absorb"
+        out, _ = eng.absorb(eng.init(D), pack_arrival_waves([_clients(2, [6])]))
+    jax.block_until_ready(out)
+    assert eng.dist.dispatches == 1  # three spans, one dispatch
+    for name in ("span_seconds", "span_cpu_seconds"):
+        counts = {
+            h["labels"]["stage"]: h["count"]
+            for h in registry.snapshot()["histograms"]
+            if h["name"] == name and h["labels"].get("engine") == kind
+        }
+        assert counts == {"init": 1, call: 1, f"{call}/place": 1, f"{call}/launch": 1}
+    wall = {s: registry.histogram("span_seconds", stage=s, engine=kind).sum
+            for s in (call, f"{call}/place", f"{call}/launch")}
+    assert wall[f"{call}/place"] + wall[f"{call}/launch"] <= wall[call]
 
 
 # ---------------------------------------------------------------------------
