@@ -45,6 +45,7 @@ import numpy as np
 
 from repro.data.partition import dirichlet_partition
 from repro.data.synthetic import FeatureDataset, make_feature_dataset
+from repro.kernels.fed3r_stats import BK
 from repro.launch.mesh import data_parallel_size
 
 
@@ -145,6 +146,18 @@ class PackedClients(NamedTuple):
         return int(self.mask.sum())
 
 
+def _count_stats_rows(sizes: Sequence[int], capacity_rows: int) -> None:
+    """``stats_rows{kind}`` in the process-global registry: the real rows,
+    the packed capacity (slots × capacity), and the rows the statistics
+    kernel computes (each client's rows rounded up to its row block)."""
+    from repro.federated.telemetry import get_telemetry  # repro.federated imports this module
+
+    t = get_telemetry()
+    t.counter("stats_rows", kind="real").inc(sum(sizes))
+    t.counter("stats_rows", kind="capacity").inc(capacity_rows)
+    t.counter("stats_rows", kind="kernel").inc(sum(-(-n // BK) * BK for n in sizes))
+
+
 def pack_client_shards(
     clients: Sequence[Tuple[np.ndarray, np.ndarray]],
     clients_per_shard: int,
@@ -204,6 +217,8 @@ def pack_client_shards(
         labels[slot, :n_k] = y
         mask[slot, :n_k] = 1.0
         slot_ids[slot] = ids[i]
+
+    _count_stats_rows(sizes, n_slots * cap)
 
     def shard(a: np.ndarray) -> np.ndarray:
         return a.reshape((n_shards, clients_per_shard) + a.shape[1:])

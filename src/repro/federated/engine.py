@@ -41,7 +41,9 @@ Exactness: per-client blocks have identical padded shapes, and the
 client fold is a strict left fold in sorted-id order regardless of how
 clients land in shards — so A and b are *bit-identical* under client
 reordering AND re-sharding (different ``clients_per_shard``), the paper's
-§4.3 invariance made exact rather than approximate.
+§4.3 invariance made exact rather than approximate.  On the kernel path a
+client's statistics cover its live rows only (the extent read from its
+mask, in fixed row blocks), so they are the same bits at any capacity.
 """
 from __future__ import annotations
 
@@ -70,10 +72,22 @@ from repro.sharding.hints import hint
 from repro.sharding.specs import replicated
 
 
-def _ab(z: jax.Array, y: jax.Array, use_kernel: Optional[bool]):
-    """The (A, b) GEMM backend over masked design matrices."""
+def _ab(
+    z: jax.Array,
+    y: jax.Array,
+    use_kernel: Optional[bool],
+    mask: Optional[jax.Array] = None,
+):
+    """The (A, b) GEMM backend over masked design matrices.
+
+    The kernel computes only up to the block's live extent, its last real
+    row plus one, read from ``mask``."""
     if resolve_use_kernel(use_kernel):
-        return fed3r_stats_kernel(z, y)
+        rows = None
+        if mask is not None:
+            idx = jnp.arange(1, mask.shape[0] + 1, dtype=jnp.int32)
+            rows = jnp.max(jnp.where(mask > 0, idx, 0))
+        return fed3r_stats_kernel(z, y, rows)
     return fed3r.gram(z), fed3r.gram(z, y)
 
 
@@ -87,7 +101,7 @@ def shard_stats(
 ) -> Fed3RStats:
     """Fused masked statistics of one padded sample block (Eq. 5/6)."""
     z, y, n = fed3r.masked_design(features, labels, n_classes, mask)
-    A, b = _ab(z, y, use_kernel)
+    A, b = _ab(z, y, use_kernel, mask)
     return Fed3RStats(A=A, b=b, n=n)
 
 
@@ -196,7 +210,7 @@ class AccumulationEngine(DistDispatchMixin):
         """
         feats, labels, mask = block
         z, y, n = fed3r.masked_design(feats, labels, self.cfg.n_classes, mask)
-        A, b = _ab(z, y, self.cfg.use_kernel)
+        A, b = _ab(z, y, self.cfg.use_kernel, mask)
         if self.wire.kind == "fp32":
             stats = fed3r.merge(acc.stats, Fed3RStats(A=A, b=b, n=n))
         else:

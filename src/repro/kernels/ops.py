@@ -21,9 +21,12 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def fed3r_stats(Z: jax.Array, Y: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """Fused FED3R statistics (A, b) = (ZᵀZ, ZᵀY)."""
-    return fed3r_stats_pallas(Z, Y, interpret=_interpret())
+def fed3r_stats(
+    Z: jax.Array, Y: jax.Array, rows: Optional[jax.Array] = None
+) -> Tuple[jax.Array, jax.Array]:
+    """Fused FED3R statistics (A, b) = (ZᵀZ, ZᵀY) over the first ``rows``
+    rows (all by default; the rest must be zero)."""
+    return fed3r_stats_pallas(Z, Y, rows, interpret=_interpret())
 
 
 def chol_gram(

@@ -78,6 +78,29 @@ def test_packer_canonical_order_is_input_order_invariant():
         assert np.array_equal(a, b)
 
 
+def test_packer_counts_stats_rows():
+    """``stats_rows``: real rows, slots × capacity, and the rows the
+    statistics kernel computes (each client rounded up to its row block)."""
+    from repro.federated.telemetry import Telemetry, set_telemetry
+    from repro.kernels.fed3r_stats import BK
+
+    sizes = [5, BK + 1, 2 * BK, 1]
+    prev = set_telemetry(Telemetry())
+    try:
+        pack_client_shards(_make_clients(3, sizes), 3, round_to=8)
+        t = set_telemetry(prev)
+    except BaseException:
+        set_telemetry(prev)
+        raise
+    rows = {k: t.counter("stats_rows", kind=k).value
+            for k in ("real", "capacity", "kernel")}
+    assert rows == {
+        "real": 3 * BK + 7,
+        "capacity": 2 * 3 * 2 * BK,  # 2 shards of 3 slots, 2·BK rows each
+        "kernel": BK + 2 * BK + 2 * BK + BK,
+    }
+
+
 def test_packer_rejects_oversized_client():
     clients = _make_clients(2, [4, 9])
     with pytest.raises(ValueError):
@@ -169,6 +192,31 @@ def test_engine_bit_identical_under_resharding(cps):
     )
     eng = AccumulationEngine(EngineConfig(n_classes=C))
     got = eng.accumulate(eng.init(D), pack_client_shards(clients, cps, max_n=16))
+    assert np.array_equal(np.asarray(ref.stats.A), np.asarray(got.stats.A))
+    assert np.array_equal(np.asarray(ref.stats.b), np.asarray(got.stats.b))
+
+
+KERNEL_SIZES = [150, 3, 260, 9, 1, 128]  # across several row blocks
+
+
+@pytest.mark.parametrize("layout", [
+    dict(max_n=600),  # padded further: more dead row blocks
+    dict(perm=[4, 0, 5, 2, 1, 3]),
+    dict(cps=1), dict(cps=3), dict(cps=6),
+], ids=["capacity", "permutation", "cps1", "cps3", "cps6"])
+def test_engine_kernel_path_bit_identical(layout):
+    """On the kernel path a client's statistics depend on its real rows
+    only: the fixed row block makes A and b the same bits at any capacity,
+    and the left fold keeps them so under reordering and re-sharding."""
+    clients = _make_clients(10, KERNEL_SIZES)
+    eng = AccumulationEngine(EngineConfig(n_classes=C, use_kernel=True))
+    ref = eng.accumulate(eng.init(D), pack_client_shards(clients, 2))
+    perm = layout.get("perm", list(range(len(clients))))
+    packed = pack_client_shards(
+        [clients[i] for i in perm], layout.get("cps", 2), client_ids=perm,
+        max_n=layout.get("max_n"),
+    )
+    got = eng.accumulate(eng.init(D), packed)
     assert np.array_equal(np.asarray(ref.stats.A), np.asarray(got.stats.A))
     assert np.array_equal(np.asarray(ref.stats.b), np.asarray(got.stats.b))
 

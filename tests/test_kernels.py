@@ -26,6 +26,47 @@ def test_fed3r_stats_kernel(n, d, C, dtype, rng):
     assert A.dtype == jnp.float32  # fp32 accumulation regardless of input
 
 
+def _extent_case(case, n, bk):
+    """(live-row mask, extent) of one case; the extent is the last live
+    row plus one."""
+    live = np.zeros(n, bool)
+    if case == "first_block":
+        live[: bk // 3] = True
+    elif case == "not_multiple":
+        live[: 2 * bk - 5] = True
+    elif case == "full":
+        live[:] = True
+    elif case == "holes":
+        live[[0, 3, bk - 1, bk + 7, 2 * bk + 1]] = True
+    idx = np.flatnonzero(live)
+    return live, int(idx[-1]) + 1 if idx.size else 0
+
+
+@pytest.mark.parametrize(
+    "case", ["empty", "first_block", "not_multiple", "full", "holes"]
+)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_fed3r_stats_kernel_live_extent(case, dtype, rng):
+    """Only rows below the extent count; the row blocks wholly past it are
+    never read (NaN there would poison the tile) and an extent of 0 gives
+    exact zeros."""
+    from repro.kernels.fed3r_stats import BK
+
+    n, d, C = 3 * BK + 8, 40, 7  # the last block is partly padding
+    live, rows = _extent_case(case, n, BK)
+    Z = np.asarray(jax.random.normal(rng, (n, d), dtype), np.float64)
+    Y = np.eye(C)[np.asarray(jax.random.randint(rng, (n,), 0, C))]
+    Z[~live], Y[~live] = 0.0, 0.0  # a masked design's padding
+    dead = -(-rows // BK) * BK
+    Z[dead:], Y[dead:] = np.nan, np.nan
+    A, b = fed3r_stats(jnp.asarray(Z, dtype), jnp.asarray(Y, dtype), jnp.int32(rows))
+    Zr, Yr = Z[:rows], Y[:rows]  # float64 reference over the live rows
+    np.testing.assert_allclose(np.asarray(A), Zr.T @ Zr, rtol=1e-5, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(b), Zr.T @ Yr, rtol=1e-5, atol=1e-4)
+    if rows == 0:
+        assert not np.any(np.asarray(A)) and not np.any(np.asarray(b))
+
+
 @pytest.mark.parametrize("n,d,D", [(64, 32, 64), (200, 100, 257), (130, 64, 512)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_rff_kernel(n, d, D, dtype, rng):

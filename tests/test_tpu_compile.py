@@ -65,6 +65,18 @@ def test_fed3r_stats_compiles(one_chip):
     _assert_kernel(compiled)
 
 
+@pytest.mark.parametrize("capacity", [64, 1472])  # the deployments' round shapes
+def test_fed3r_stats_live_extent_compiles(one_chip, capacity):
+    """The engine's call: a client's padded block and its live row extent."""
+    compiled = fed3r_stats_pallas.lower(
+        _spec((capacity, D), jnp.float32, one_chip),
+        _spec((capacity, C), jnp.float32, one_chip),
+        _spec((), jnp.int32, one_chip),
+        interpret=False,
+    ).compile()
+    _assert_kernel(compiled)
+
+
 def test_chol_gram_compiles(one_chip):
     compiled = chol_gram_pallas.lower(
         _spec((D, D), jnp.float32, one_chip),
