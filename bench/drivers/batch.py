@@ -1,5 +1,11 @@
 """Batch statistics: every round through ``AccumulationEngine.accumulate``,
-then ``fed3r.solve``.  One unit is one whole pass over the federation."""
+then ``fed3r.solve``.  One unit is one whole pass over the federation.
+
+A driver for a backbone configuration subclasses this one: it sets
+``feature_fn`` (the program's forward, which the engine runs inside its
+scan over the packed tokens), puts the program's weights in ``params``,
+and adds the forward's work (the backbone module's ``flops``) into
+``flops``."""
 from __future__ import annotations
 
 import time
@@ -18,6 +24,7 @@ from repro.federated.engine import AccumulationEngine, EngineConfig
 
 class Driver:
     unit = "pass"
+    feature_fn = None  # None: the inputs are the features
 
     def __init__(self, config: dict, traffic: dict, fed, mesh=None, seed: int = 0):
         if mesh is not None:
@@ -25,7 +32,9 @@ class Driver:
         self.d, self.C = fed.feature_dim, fed.n_classes
         self.ridge_lambda = config["assumed"]["ridge_lambda"]
         self.n_rounds = len(fed.rounds)
-        self.engine = AccumulationEngine(EngineConfig(n_classes=self.C))
+        self.engine = AccumulationEngine(EngineConfig(n_classes=self.C),
+                                         feature_fn=self.feature_fn)
+        self.params = None  # the feature_fn's weights, on the device
         self.rounds: List[PackedClients] = []
         for r in range(self.n_rounds):
             p = pack_client_shards(
@@ -51,7 +60,7 @@ class Driver:
             for packed in self.rounds:
                 with TraceAnnotation("round"):
                     t0 = time.perf_counter()
-                    acc = self.engine.accumulate(acc, packed)
+                    acc = self.engine.accumulate(acc, packed, self.params)
                     self.round_spans_s.append(time.perf_counter() - t0)
             with TraceAnnotation("solve"):
                 W = fed3r.solve(acc.stats, self.ridge_lambda)

@@ -9,7 +9,33 @@ a file of its own, found by the name ``BENCHMARK.json`` gives it:
 * ``bench/limits/<workload>.json``: the limit of each number compared;
 * ``bench/end_to_end/<metric>.py`` and ``bench/layer_metrics/<metric>.py``:
   a ``read(ctx)`` that returns the metric's value, or ``None`` where the
-  run holds nothing to read.
+  run holds nothing to read.  ``ctx.telemetry`` is the program's registry
+  (``snapshot()``) as the window left it: it is reset as the window opens.
+
+A configuration that runs a backbone holds a ``backbone`` object:
+``reference`` names ``bench/backbones/<reference>.py``, ``model`` holds its
+published widths and the cut as plain data, and ``inputs`` is ``{"kind":
+"tokens", "vocab": V, "seq_len": {"median": .., "sigma": .., "max": ..}}``
+(``bench.generator``).  ``feature_dim`` stays the pooled width.  The
+module is the backbone's plain reference and imports nothing of the
+program; ``setup`` loads it once, and the federation carries it as
+``backbone_module`` to the reference and the driver:
+
+* ``ROWS``: samples per block of the reference's forward, so that a block
+  fits on the chip once the program's state is freed;
+* ``init(model, seed) -> dict[str, array]``: float32 weights from a 32-bit
+  seed, best made on the device in one jitted call; the generator keeps
+  them on the host as numpy;
+* ``features(weights, tokens, lengths, model, precision) -> (rows, d)``:
+  the forward over one block of ``ROWS`` rows, pooled over each row's real
+  tokens, traceable under ``jax.jit``, its products at ``precision``:
+  "highest" for the reference, "high" for the control
+  (``bench.reference.matmul`` gives both).  A module whose configuration
+  states less than fp32 takes "high" to mean its own control, the step
+  below the precision it states (int8 or fp8 for bf16);
+* ``flops(model, lengths)`` and ``bytes(model, lengths)``: the least work
+  of that forward over the real tokens; a driver adds ``flops`` into its
+  units' work, and ``bytes`` is there for a roofline reader of the forward.
 """
 from __future__ import annotations
 
@@ -70,17 +96,27 @@ def load_limits(workload: str) -> Dict[str, float]:
     return _json(BENCH_DIR / "limits" / f"{workload}.json")
 
 
-def load_driver(name: str):
-    return importlib.import_module(f"bench.drivers.{name}").Driver
-
-
-def load_reader(kind: str, name: str) -> Callable:
-    """``read`` of ``bench/<kind>/<name>.py`` (names may hold dots)."""
+def _module(kind: str, name: str):
+    """``bench/<kind>/<name>.py`` as a module (names may hold dots)."""
     path = BENCH_DIR / kind / f"{name}.py"
     spec = importlib.util.spec_from_file_location(f"bench.{kind}.{name}", path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
-    return module.read
+    return module
+
+
+def load_driver(name: str):
+    return importlib.import_module(f"bench.drivers.{name}").Driver
+
+
+def load_backbone(name: str):
+    """The plain reference module ``bench/backbones/<name>.py``."""
+    return _module("backbones", name)
+
+
+def load_reader(kind: str, name: str) -> Callable:
+    """``read`` of ``bench/<kind>/<name>.py``."""
+    return _module(kind, name).read
 
 
 def cell(spec: dict, workload: str) -> dict:
@@ -190,7 +226,9 @@ def setup(workload: str, seed: int, *, spec: Optional[dict] = None,
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     seed = int(seed) % 2**64
     t0 = time.perf_counter()
-    fed = generator.make_federation(config, seed, traffic.get("warm_rounds", 0))
+    backbone = config.get("backbone")
+    module = None if backbone is None else load_backbone(backbone["reference"])
+    fed = generator.make_federation(config, seed, traffic.get("warm_rounds", 0), module)
     t1 = time.perf_counter()
     mesh = _mesh(w["chips"], traffic["aggregation"])
     drv = load_driver(traffic["driver"])(config, traffic, fed, mesh=mesh, seed=seed)
@@ -214,6 +252,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     from jax.profiler import TraceAnnotation
 
     from bench import peaks, reference
+    from repro.federated.telemetry import get_telemetry
 
     t_process = time.perf_counter() if t_process is None else t_process
     limits = load_limits(workload) if limits is None else limits
@@ -236,6 +275,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     if trace:
         jax.profiler.start_trace(trace_dir)
     units = []
+    # the readers count the window's work alone, not set-up's or warm-up's
+    get_telemetry().reset()
     # the objects that live on are not walked again by the collector inside
     # the window: a run that compiled and one that loaded from the cache then
     # time the same work
@@ -250,6 +291,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
                 break
     window_s = time.perf_counter() - t0
     listening[0] = False
+    telemetry = get_telemetry().snapshot()
     jax.monitoring.unregister_event_duration_listener(on_compile)
     if trace:
         jax.profiler.stop_trace()
@@ -262,7 +304,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     gc.unfreeze()
     gc.collect()
     lam = run.config["assumed"]["ridge_lambda"]
+    t_ref = time.perf_counter()
     readings = check_answers(answers, run.fed, lam, drv.groups)
+    reference_s = time.perf_counter() - t_ref
     failed = sum(any(r[k] > limits[k] for k in r) for r in readings)
     compared = reference.worst(readings) if readings else {}
     compared["window_compiles"] = float(len(compiles))
@@ -276,7 +320,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         workload=w, config=run.config, traffic=run.traffic, unit=drv.unit, units=units,
         window_s=window_s, setup_s=setup_s, spans=spans, chips=w["chips"], peak=peak,
         d=run.fed.feature_dim, C=run.fed.n_classes, trace=None, busy_s=None,
-        trace_window_s=None,
+        trace_window_s=None, telemetry=telemetry,
     )
     device = {"platform": devices[0].platform, "kind": kind, "count": len(devices),
               "memory_peak_bytes": memory_peak}
@@ -314,8 +358,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
             f"{1e3 * float(np.median(lat))!r} ms", file=sys.stderr)
     if compiles:
         log(f"[bench] compiled inside the window: {compiles[:5]}", file=sys.stderr)
-    log(f"[bench] {len(readings)} answers checked against the reference, {failed} failed",
-        file=sys.stderr)
+    log(f"[bench] {len(readings)} answers checked against the reference, {failed} failed; "
+        f"reference_s {reference_s!r}", file=sys.stderr)
     for k, v in compared.items():
         log(f"[bench] compared {k} {v!r} limit {limits[k]!r} "
             f"{'ok' if v <= limits[k] else 'FAIL'}", file=sys.stderr)

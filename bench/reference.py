@@ -1,20 +1,24 @@
 """The plain reference and the comparison that decides ``correct``.
 
 The reference imports nothing of the program.  It takes the generator's
-unpadded features, in the pass's arrival order, and folds them as the
-closed form's sums are defined in fp32: each client's (or each wave's)
-statistics over its own rows, in blocks of ``BLOCK`` rows with plain
-``jax.numpy`` products at ``"highest"``, added one after another into the
-running sums in arrival order (clients of a round or wave in id order).  The
-program folds in that order too, so the two share the rounding of the
-long running sums and differ by the products and short sums alone.  The
-solve runs on the host in float64.
+unpadded features (for a backbone configuration, its own forward pass over
+the generator's tokens, ``backbone_features``), in the pass's arrival
+order, and folds them as the closed form's sums are defined in fp32: each
+client's (or each wave's) statistics over its own rows, in blocks of
+``BLOCK`` rows with plain ``jax.numpy`` products at ``"highest"``, added one
+after another into the running sums in arrival order (clients of a round or
+wave in id order).  The program folds in that order too, so the two share
+the rounding of the long running sums and differ by the products and short
+sums alone.  The solve runs on the host in float64.
 
 ``how`` names the contraction: ``"fp32"`` is the reference itself;
 ``"bf16x3"`` is the control, the same computation one precision step
 below (three bf16 products for each fp32 product, as ``Precision.HIGH``
 does on a TPU, written out so that it means the same on every backend);
-``"bf16"`` is one bf16 pass over bf16 features.
+``"bf16"`` is one bf16 pass over bf16 features.  A backbone's forward
+runs its products at the matching precision (``PRECISION``): "highest"
+for the reference, "high" (three bf16 products, ``matmul``) for the
+control.
 """
 from __future__ import annotations
 
@@ -29,13 +33,15 @@ import scipy.linalg
 from bench.generator import Federation
 
 BLOCK = 128  # rows per reference block
+# a fold's contraction → the precision of a backbone's products
+PRECISION = {"fp32": "highest", "bf16x3": "high", "bf16": "bfloat16"}
+_HOW = {v: k for k, v in PRECISION.items()}
+ROWS_T = (((0,), (0,)), ((), ()))  # aᵀb over the rows
 
 
-def _rows_t(a, b, precision=None):
-    return jax.lax.dot_general(
-        a, b, (((0,), (0,)), ((), ())), precision=precision,
-        preferred_element_type=jnp.float32,
-    )
+def _dot(a, b, dims, precision=None):
+    return jax.lax.dot_general(a, b, dims, precision=precision,
+                               preferred_element_type=jnp.float32)
 
 
 def _split_bf16(x: jax.Array):
@@ -48,17 +54,48 @@ def _split_bf16(x: jax.Array):
     return hi.astype(jnp.bfloat16), (x - hi).astype(jnp.bfloat16)
 
 
-def contract(a: jax.Array, b: jax.Array, how: str) -> jax.Array:
-    """aᵀb over the rows, into fp32."""
+def _product(a: jax.Array, b: jax.Array, dims, how: str) -> jax.Array:
     if how == "fp32":
-        return _rows_t(a, b, jax.lax.Precision.HIGHEST)
+        return _dot(a, b, dims, jax.lax.Precision.HIGHEST)
     if how == "bf16x3":
         a_hi, a_lo = _split_bf16(a)
         b_hi, b_lo = _split_bf16(b)
-        return _rows_t(a_hi, b_hi) + (_rows_t(a_hi, b_lo) + _rows_t(a_lo, b_hi))
+        return _dot(a_hi, b_hi, dims) + (_dot(a_hi, b_lo, dims) + _dot(a_lo, b_hi, dims))
     if how == "bf16":
-        return _rows_t(a.astype(jnp.bfloat16), b.astype(jnp.bfloat16))
+        return _dot(a.astype(jnp.bfloat16), b.astype(jnp.bfloat16), dims)
     raise ValueError(f"unknown contraction {how!r}")
+
+
+def contract(a: jax.Array, b: jax.Array, how: str) -> jax.Array:
+    """aᵀb over the rows, into fp32."""
+    return _product(a, b, ROWS_T, how)
+
+
+def matmul(x: jax.Array, w: jax.Array, precision: str = "highest") -> jax.Array:
+    """x @ w over x's last axis and w's first, into fp32, at ``precision``:
+    "highest" (fp32), "high" (three bf16 products, written out as
+    ``contract``'s ``bf16x3``, so it means the same on every backend) or
+    "bfloat16" (one pass).  For a backbone module's products."""
+    return _product(x, w, (((x.ndim - 1,), (0,)), ((), ())), _HOW[precision])
+
+
+def backbone_features(fed: Federation, rows: np.ndarray, how: str = "fp32") -> np.ndarray:
+    """The backbone module's pooled features of the samples ``rows``: one
+    jitted forward over blocks of the module's ``ROWS`` rows, its products
+    at the precision that matches ``how``."""
+    module = fed.backbone_module
+    step = module.ROWS
+    forward = jax.jit(partial(module.features, model=fed.backbone["model"],
+                              precision=PRECISION[how]))
+    weights = jax.device_put(fed.weights)
+    blocks = []
+    for lo in range(0, len(rows), step):
+        take = rows[lo:lo + step]
+        tokens = np.zeros((step, fed.tokens.shape[1]), np.int32)
+        lengths = np.ones(step, np.int32)  # rows past the end: one token of id 0
+        tokens[:len(take)], lengths[:len(take)] = fed.tokens[take], fed.lengths[take]
+        blocks.append(forward(weights, tokens, lengths))
+    return np.concatenate([np.asarray(b) for b in blocks])[:len(rows)]
 
 
 @partial(jax.jit, static_argnames=("n_classes", "how"))
@@ -112,7 +149,8 @@ def statistics(fed: Federation, ridge_lambda: float, groups: Sequence[np.ndarray
     edges = np.concatenate([[0], np.cumsum([len(r) for r in rows])])
     rows = np.concatenate(rows)
     feats = np.zeros((len(rows) + BLOCK, d), np.float32)
-    feats[:len(rows)] = fed.features[rows]
+    feats[:len(rows)] = (fed.features[rows] if fed.backbone is None
+                         else backbone_features(fed, rows, how))
     labels = np.zeros(len(rows) + BLOCK, np.int32)
     labels[:len(rows)] = fed.labels[rows]
     feats, labels = jax.device_put(feats), jax.device_put(labels)

@@ -1,4 +1,8 @@
+import sys
+
 import pytest
+
+import bench.drivers
 
 # A deployment small enough for the CPU: the shapes of the real ones
 # (C > d, lognormal sizes, rounds of 10, more rounds than a warm start
@@ -24,3 +28,19 @@ TINY = {
 @pytest.fixture
 def tiny():
     return {**TINY, "assumed": dict(TINY["assumed"])}
+
+
+@pytest.fixture
+def drivers_from(monkeypatch):
+    """Let ``bench.drivers.<name>`` find drivers in a test's own directory
+    first, as it finds a new file in ``bench/drivers``."""
+    added = []
+
+    def add(directory, *names):
+        monkeypatch.setattr(bench.drivers, "__path__",
+                            [str(directory), *bench.drivers.__path__])
+        added.extend(names)
+
+    yield add
+    for name in added:
+        sys.modules.pop(f"bench.drivers.{name}", None)

@@ -1,5 +1,6 @@
 """The harness on the CPU at a tiny size: discovery by name, the generator,
 the reference comparison, the result line, and the refusal of a CPU."""
+import hashlib
 import json
 import shutil
 
@@ -25,6 +26,36 @@ def test_generator_is_deterministic_under_seed(tiny):
     assert key(a) == key(c)
     assert a.n_samples == tiny["n_samples"]
     assert a.labels.min() >= 0 and a.labels.max() < tiny["n_classes"]
+
+
+# sha256 of make_federation(TINY, seed, warm_rounds) as the generator drew it
+# before a configuration could name a backbone: a configuration without one
+# must draw the same arrays, bit for bit
+GAUSSIAN_DIGESTS = {
+    (7, 0): "f36042eac32443f22d0da3d9a709905c7dd136cd46e9dada420e99d2b16957a8",
+    (7, 8): "15217aeb8ab427f6650a4018956da7772b74ce43094794729b649f88f31458c4",
+    (2**31 + 5, 0): "62943a45076b4c01c0f12562870c9d2f36a83240cbbce1ea0dde468544ba9028",
+    (2**31 + 5, 8): "b3aee8a57ce84573e43f555e7947ced9afaea992da450dc5567badbaef06b7c3",
+}
+
+
+def _digest(fed) -> str:
+    h = hashlib.sha256()
+    for a in (fed.features, fed.labels, fed.offsets, *fed.rounds):
+        a = np.ascontiguousarray(a)
+        h.update(f"{a.dtype.str}{a.shape}".encode())
+        h.update(a.tobytes())
+    h.update(str(fed.n_classes).encode())
+    return h.hexdigest()
+
+
+@pytest.mark.parametrize("seed, warm", sorted(GAUSSIAN_DIGESTS))
+def test_gaussian_federation_is_pinned(tiny, seed, warm):
+    fed = generator.make_federation(tiny, seed, warm)
+    assert _digest(fed) == GAUSSIAN_DIGESTS[seed, warm]
+    assert fed.feature_dim == tiny["feature_dim"] and fed.inputs is fed.features
+    assert fed.tokens is None and fed.lengths is None and fed.weights is None
+    assert fed.backbone is None and fed.backbone_module is None
 
 
 def test_reference_rejects_statistics_of_bf16_features(tiny):
@@ -143,3 +174,51 @@ def test_traced_run_fails_loudly_where_a_listed_metric_reads_nothing(tiny, tmp_p
     with pytest.raises(harness.MissingMetric, match="renamed_roofline"):
         harness.run_cell("landmarks-batch", 5, 0.2, True, spec=spec, config=tiny,
                          on_chip=False, log=lambda *a, **k: None)
+
+
+COUNTING_DRIVER = """
+from bench.drivers.batch import Driver as Batch
+from repro.federated.telemetry import get_telemetry
+
+
+class Driver(Batch):
+    def __init__(self, *args, **kwargs):
+        super().__init__(*args, **kwargs)
+        get_telemetry().counter("bench_test_setup").inc()
+
+    def step(self):
+        get_telemetry().counter("bench_test_units").inc()
+        return super().step()
+"""
+
+
+def test_readers_get_the_windows_telemetry(tiny, tmp_path, monkeypatch, drivers_from):
+    """The registry is reset as the window opens: a counter bumped in set-up
+    and warm-up reads 0 in ``ctx.telemetry``, one bumped in each of the
+    window's units reads their number."""
+    for sub in ("configs", "limits", "end_to_end"):
+        shutil.copytree(harness.BENCH_DIR / sub, tmp_path / sub)
+    (tmp_path / "drivers").mkdir()
+    (tmp_path / "drivers" / "counting.py").write_text(COUNTING_DRIVER)
+    drivers_from(tmp_path / "drivers", "counting")
+    (tmp_path / "traffic").mkdir()
+    traffic = json.loads((harness.BENCH_DIR / "traffic" / "rounds-batch.json").read_text())
+    (tmp_path / "traffic" / "rounds-counting.json").write_text(
+        json.dumps({**traffic, "driver": "counting"}))
+    for name in ("setup", "units"):
+        (tmp_path / "end_to_end" / f"test_{name}.py").write_text(
+            "def read(ctx):\n"
+            f"    return [c['value'] for c in ctx.telemetry['counters']\n"
+            f"            if c['name'] == 'bench_test_{name}'][0]\n")
+    spec = harness.load_spec()
+    spec["workloads"].append({"name": "counting-batch", "config": "landmarks-users-160k",
+                              "traffic": "rounds-counting", "chips": 1, "why": "test"})
+    spec["end_to_end"] = [{"name": f"test_{n}", "unit": "1", "better": "higher",
+                           "bound": 0.05, "source": "host_clock"} for n in ("setup", "units")]
+    monkeypatch.setattr(harness, "BENCH_DIR", tmp_path)
+    r = harness.run_cell("counting-batch", 5, 0.3, False, spec=spec, config=tiny,
+                         limits=harness.load_limits("landmarks-batch"), on_chip=False,
+                         log=lambda *a, **k: None)
+    assert r["correct"] is True
+    assert r["metrics"]["test_setup"]["value"] == 0
+    assert r["metrics"]["test_units"]["value"] == r["attempted"] >= 1

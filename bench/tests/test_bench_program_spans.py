@@ -220,3 +220,26 @@ def test_recorded_chip_trace_with_program_spans(registry, driver, tmp_path):
         assert share is None
     else:
         assert 0 < share <= _reader("idle_share.stream")(ctx)
+
+
+@pytest.mark.parametrize("driver", ["batch", "stream"])
+def test_recorded_chip_trace_reads_the_same_after_a_reset(registry, driver, tmp_path):
+    """The harness resets the registry, its span log with it, as the window
+    opens.  The readers take the window's spans alone, so on the traces
+    recorded on the chip they read the same whether the log also holds
+    spans from before the window (an earlier run's, one second earlier) or
+    only the window's, as after the reset."""
+    path, log = _recorded(driver, tmp_path)
+    trace = tr.load(path)
+    lo, hi = tr.window(trace)
+    ctx = SimpleNamespace(trace=trace, busy_s=tr.mean_busy_s(trace),
+                          trace_window_s=(hi - lo) / 1e9)
+    names = ("api_cpu_us", "api_idle_share.stream")
+    registry.span_log.extend([(s, a - 10**9, b - 10**9, c) for s, a, b, c in log] + log)
+    stale = {n: _reader(n)(ctx) for n in names}
+    registry.reset()
+    assert not registry.span_log
+    registry.span_log.extend(log)
+    assert {n: _reader(n)(ctx) for n in names} == stale
+    assert stale["api_cpu_us"] is not None
+    assert (stale["api_idle_share.stream"] is None) is (driver == "batch")
