@@ -27,6 +27,7 @@ ARCH_MODULES = [
     "llama4_scout_17b_a16e",
     "whisper_large_v3",
     "fed3r_mnv2_proxy",
+    "nemotron3_nano_30b_a3b",
 ]
 
 ASSIGNED_ARCHS = [

@@ -23,13 +23,13 @@ from typing import Optional, Tuple
 class ModelConfig:
     """Complete architectural description of one backbone.
 
-    The same dataclass covers all six architecture families (dense / moe /
-    ssm / hybrid / vlm / audio); family-specific fields default to inert
-    values so that dense configs stay small.
+    The same dataclass covers all seven architecture families (dense / moe /
+    ssm / hybrid / vlm / audio / nemotron_h); family-specific fields default
+    to inert values so that dense configs stay small.
     """
 
     name: str
-    arch_type: str  # dense | moe | ssm | hybrid | vlm | audio
+    arch_type: str  # dense | moe | ssm | hybrid | vlm | audio | nemotron_h
     n_layers: int
     d_model: int
     n_heads: int
@@ -47,8 +47,10 @@ class ModelConfig:
     attn_logit_softcap: Optional[float] = None
 
     # --- MLP ---------------------------------------------------------------
-    mlp_type: str = "swiglu"  # swiglu | gelu
+    mlp_type: str = "swiglu"  # swiglu | geglu | gelu | relu2 (down(relu(up x)²), no bias)
     norm_type: str = "rmsnorm"  # rmsnorm | layernorm
+    norm_eps: Optional[float] = None  # None: 1e-6 for rmsnorm, 1e-5 for layernorm
+    use_rope: bool = True  # False: attention takes no position signal
     tie_embeddings: bool = True
 
     # --- MoE ---------------------------------------------------------------
@@ -59,6 +61,11 @@ class ModelConfig:
     router_aux_coef: float = 0.01
     capacity_factor: float = 1.25
     moe_every: int = 1  # MoE layer every N layers (1 = all layers MoE)
+    # nemotron_h's MoE (models/moe.py held_moe_*): a sigmoid router with a
+    # correction bias over all n_experts; this chip holds experts_held =
+    # (offset, count) of them; the chosen weights are scaled by routed_scaling
+    routed_scaling: float = 1.0
+    experts_held: Tuple[int, int] = (0, 0)
 
     # --- SSM (Mamba2 / SSD) -------------------------------------------------
     ssm_state: int = 0
@@ -67,11 +74,18 @@ class ModelConfig:
     ssm_chunk: int = 256
     ssm_conv: int = 4
     ssm_ngroups: int = 1
+    ssm_d_inner: Optional[int] = None  # None: ssm_expand · d_model
+    ssm_group_norm: bool = False  # gated RMSNorm per group of d_inner/ngroups
 
     # --- hybrid (RecurrentGemma / Griffin) ----------------------------------
     block_pattern: Tuple[str, ...] = ()  # e.g. ("rec", "rec", "attn")
     lru_width: int = 0
     local_window: int = 2048
+
+    # --- single-mixer stacks (Nemotron-H) ------------------------------------
+    # one character a layer, x + mixer(norm(x)): M Mamba-2, E MoE, * attention;
+    # the first n_layers characters are the stack
+    mixer_pattern: str = ""
 
     # --- VLM (Qwen2-VL) ------------------------------------------------------
     mrope_sections: Tuple[int, ...] = ()  # rotary dim split (t, h, w)
@@ -128,7 +142,14 @@ class ModelConfig:
     @property
     def d_inner(self) -> int:
         """Mamba2 inner width."""
+        if self.ssm_d_inner is not None:
+            return self.ssm_d_inner
         return self.ssm_expand * self.d_model
+
+    @property
+    def mixers(self) -> str:
+        """The single-mixer stack's layer kinds, one character a layer."""
+        return self.mixer_pattern[: self.n_layers]
 
     @property
     def ssm_nheads(self) -> int:
@@ -160,7 +181,9 @@ class ModelConfig:
         return self.n_layers % len(self.block_pattern)
 
     def validate(self) -> None:
-        assert self.arch_type in ("dense", "moe", "ssm", "hybrid", "vlm", "audio"), self.arch_type
+        assert self.arch_type in (
+            "dense", "moe", "ssm", "hybrid", "vlm", "audio", "nemotron_h"
+        ), self.arch_type
         if self.arch_type != "ssm":
             assert self.n_heads % max(self.n_kv_heads, 1) == 0, "GQA requires n_heads % n_kv_heads == 0"
         if self.arch_type == "moe":
@@ -174,6 +197,13 @@ class ModelConfig:
             assert self.is_encoder_decoder and self.n_audio_frames > 0
         if self.arch_type == "vlm":
             assert self.mrope_sections and self.n_patches > 0
+        if self.arch_type == "nemotron_h":
+            assert len(self.mixer_pattern) >= self.n_layers
+            assert set(self.mixers) <= set("ME*"), self.mixer_pattern
+            offset, count = self.experts_held
+            assert count > 0 and 0 <= offset and offset + count <= self.n_experts
+            assert self.d_inner % self.ssm_headdim == 0
+            assert self.d_inner % self.ssm_ngroups == 0
 
 
 # ---------------------------------------------------------------------------

@@ -128,6 +128,9 @@ class PackedClients(NamedTuple):
     labels: np.ndarray  # (S, P, N) int32
     mask: np.ndarray  # (S, P, N) float32
     client_ids: np.ndarray  # (S, P) int32, -1 = empty slot
+    # token inputs only: (real tokens, positions computed), the non-zero ids
+    # of the real rows and slots × capacity × length; None for features
+    extract_tokens: Optional[Tuple[int, int]] = None
 
     @property
     def n_shards(self) -> int:
@@ -219,6 +222,9 @@ def pack_client_shards(
         slot_ids[slot] = ids[i]
 
     _count_stats_rows(sizes, n_slots * cap)
+    extract_tokens = None
+    if np.issubdtype(inputs.dtype, np.integer) and inputs.ndim == 3:  # token rows
+        extract_tokens = (int(np.count_nonzero(inputs)), int(inputs.size))
 
     def shard(a: np.ndarray) -> np.ndarray:
         return a.reshape((n_shards, clients_per_shard) + a.shape[1:])
@@ -226,6 +232,7 @@ def pack_client_shards(
     return PackedClients(
         inputs=shard(inputs), labels=shard(labels), mask=shard(mask),
         client_ids=slot_ids.reshape(n_shards, clients_per_shard),
+        extract_tokens=extract_tokens,
     )
 
 

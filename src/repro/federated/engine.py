@@ -263,8 +263,16 @@ class AccumulationEngine(DistDispatchMixin):
     def accumulate(
         self, acc: EngineStats, packed: PackedClients, params: Any = None
     ) -> EngineStats:
-        """Fold a packed client selection into the accumulator (one dispatch)."""
+        """Fold a packed client selection into the accumulator (one dispatch).
+
+        With a ``feature_fn`` over token inputs the call adds the packing's
+        ``extract_tokens{kind=real|computed}`` to the registry: the tokens
+        the forward runs over that are real, and all it computes."""
         span = self.dist.telemetry.span
+        if self.feature_fn is not None and packed.extract_tokens is not None:
+            real, computed = packed.extract_tokens
+            self.dist.telemetry.counter("extract_tokens", kind="real").inc(real)
+            self.dist.telemetry.counter("extract_tokens", kind="computed").inc(computed)
         with span("accumulate", engine="accumulation"):
             self.dist.dispatch()
             with span("place", engine="accumulation"):

@@ -54,11 +54,13 @@ def norm_apply(cfg: ModelConfig, p: dict, x: jax.Array) -> jax.Array:
     if cfg.norm_type == "layernorm":
         mean = jnp.mean(x, axis=-1, keepdims=True)
         var = jnp.mean(jnp.square(x - mean), axis=-1, keepdims=True)
-        y = (x - mean) * jax.lax.rsqrt(var + 1e-5)
+        eps = 1e-5 if cfg.norm_eps is None else cfg.norm_eps
+        y = (x - mean) * jax.lax.rsqrt(var + eps)
         y = y * p["scale"] + p["bias"]
     else:  # rmsnorm
         ms = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-        y = x * jax.lax.rsqrt(ms + 1e-6) * p["scale"]
+        eps = 1e-6 if cfg.norm_eps is None else cfg.norm_eps
+        y = x * jax.lax.rsqrt(ms + eps) * p["scale"]
     return y.astype(dtype)
 
 
@@ -68,7 +70,7 @@ def norm_apply(cfg: ModelConfig, p: dict, x: jax.Array) -> jax.Array:
 
 
 def mlp_init(rng, cfg: ModelConfig, d_ff: Optional[int] = None) -> dict:
-    """Gated (swiglu/geglu) or plain (gelu) MLP parameters."""
+    """Gated (swiglu/geglu) or plain (gelu, relu2) MLP parameters."""
     d, f = cfg.d_model, d_ff or cfg.d_ff
     r = jax.random.split(rng, 3)
     if cfg.mlp_type in ("swiglu", "geglu"):
@@ -77,12 +79,19 @@ def mlp_init(rng, cfg: ModelConfig, d_ff: Optional[int] = None) -> dict:
             "w_up": dense_init(r[1], (d, f)),
             "w_down": dense_init(r[2], (f, d)),
         }
+    if cfg.mlp_type == "relu2":
+        return {"w_up": dense_init(r[0], (d, f)), "w_down": dense_init(r[1], (f, d))}
     return {
         "w_up": dense_init(r[0], (d, f)),
         "b_up": jnp.zeros((f,), jnp.float32),
         "w_down": dense_init(r[1], (f, d)),
         "b_down": jnp.zeros((cfg.d_model,), jnp.float32),
     }
+
+
+def relu2(x: jax.Array) -> jax.Array:
+    """relu(x)², the non-gated activation of Nemotron-H's experts."""
+    return jnp.square(jax.nn.relu(x))
 
 
 def mlp_apply(cfg: ModelConfig, p: dict, x: jax.Array) -> jax.Array:
@@ -92,6 +101,8 @@ def mlp_apply(cfg: ModelConfig, p: dict, x: jax.Array) -> jax.Array:
         g = act(x @ p["w_gate"].astype(dt))
         u = x @ p["w_up"].astype(dt)
         return (g * u) @ p["w_down"].astype(dt)
+    if cfg.mlp_type == "relu2":
+        return relu2(x @ p["w_up"].astype(dt)) @ p["w_down"].astype(dt)
     h = jax.nn.gelu(x @ p["w_up"].astype(dt) + p["b_up"].astype(dt))
     return h @ p["w_down"].astype(dt) + p["b_down"].astype(dt)
 

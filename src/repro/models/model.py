@@ -51,6 +51,8 @@ def init_params(cfg: ModelConfig, rng: jax.Array) -> dict:
 
     if cfg.arch_type in ("dense", "moe", "vlm"):
         params["layers"] = tfm.stacked_block_init(r[2], cfg, "attn", cfg.n_layers)
+    elif cfg.arch_type == "nemotron_h":
+        params["layers"] = tfm.mixer_stack_init(r[2], cfg)
     elif cfg.arch_type == "ssm":
         params["layers"] = tfm.stacked_block_init(r[2], cfg, "ssm", cfg.n_layers)
     elif cfg.arch_type == "hybrid":
@@ -93,7 +95,7 @@ def vlm_positions_3d(cfg: ModelConfig, seq_idx: jax.Array) -> jax.Array:
 
 def _angles_for(cfg: ModelConfig, seq_idx: jax.Array) -> Optional[jax.Array]:
     """Rotary angles for a run of sequence indices. seq_idx: (S,) int32."""
-    if cfg.arch_type == "ssm" or cfg.arch_type == "audio":
+    if cfg.arch_type == "ssm" or cfg.arch_type == "audio" or not cfg.use_rope:
         return None
     if cfg.arch_type == "vlm":
         pos3 = vlm_positions_3d(cfg, seq_idx)
@@ -155,7 +157,12 @@ def forward(
     if capacity is not None and window is not None:
         capacity = min(capacity, window)
 
-    if cfg.arch_type == "hybrid":
+    if cfg.arch_type == "nemotron_h":
+        if mode != "train":
+            raise NotImplementedError("nemotron_h runs whole sequences only (no cache)")
+        real = _token_mask(batch)
+        h, new_cache, aux = tfm.apply_mixer_stack(cfg, params["layers"], x, real), None, 0.0
+    elif cfg.arch_type == "hybrid":
         h, new_cache, aux = tfm.apply_hybrid(
             cfg, params["layers"], x, angles=angles, mode=mode, cache=cache,
             decode_pos=decode_pos,
@@ -263,17 +270,80 @@ def lm_loss(cfg: ModelConfig, params: dict, batch: Dict[str, jax.Array]) -> jax.
     return ce + cfg.router_aux_coef * out.aux_loss
 
 
+def _token_mask(batch: Dict[str, jax.Array]) -> Optional[jax.Array]:
+    """A per-token ``mask`` (B, S) of the batch as booleans, or None: a
+    per-sample mask (B,), as the statistics step's packed batches carry,
+    marks no tokens."""
+    mask = batch.get("mask")
+    return mask != 0 if mask is not None and mask.ndim == 2 else None
+
+
 def extract_features(
     cfg: ModelConfig, params: dict, batch: Dict[str, jax.Array]
 ) -> jax.Array:
-    """φ(x): pooled final hidden state, (B, d_feat) fp32 — the FED3R feature map."""
+    """φ(x): pooled final hidden state, (B, d_feat) fp32 — the FED3R feature map.
+
+    With a per-token ``mask`` (B, S) in the batch (nonzero on real tokens)
+    the pooling covers the real positions alone: the mean over them, or the
+    last of them; a row without one gives zeros.  Without one every
+    position counts (a per-sample ``mask`` (B,), as the statistics step's
+    packed batches carry, is not a token mask)."""
     out = forward(cfg, params, batch, mode="train", return_logits=False)
     h = out.hidden.astype(jnp.float32)
     if cfg.arch_type == "vlm":  # pool text positions only
         h = h[:, cfg.n_patches :, :]
+    real = _token_mask(batch)
+    if real is not None:
+        if cfg.feature_pooling == "last":
+            pos = jnp.arange(h.shape[1], dtype=jnp.int32)
+            last = jnp.max(jnp.where(real, pos, -1), axis=1)
+            picked = jnp.take_along_axis(h, jnp.maximum(last, 0)[:, None, None], axis=1)
+            return jnp.where(last[:, None] >= 0, picked[:, 0, :], 0.0)
+        count = jnp.maximum(jnp.sum(real, axis=1, keepdims=True), 1).astype(jnp.float32)
+        return jnp.sum(jnp.where(real[..., None], h, 0.0), axis=1) / count
     if cfg.feature_pooling == "last":
         return h[:, -1, :]
     return jnp.mean(h, axis=1)
+
+
+# Activation bytes one block of a token forward may take (of a v5e's 16 GB,
+# beside the weights and the statistics).
+ACTIVATION_BYTES = 2 << 30
+
+
+def feature_block_rows(cfg: ModelConfig, seq_len: int) -> int:
+    """Rows a block of :func:`token_feature_fn` holds: the widest per-token
+    activation of a layer (attention's scores over the sequence, an MLP's or
+    expert's hidden, Mamba's in-projection and SSD chunk products) at about
+    three live copies of four bytes, over ``ACTIVATION_BYTES``."""
+    widths = [cfg.d_model, cfg.d_ff, cfg.d_expert, cfg.n_heads * seq_len]
+    if cfg.ssm_state:
+        widths += [2 * cfg.d_inner + 2 * cfg.ssm_ngroups * cfg.ssm_state,
+                   cfg.ssm_nheads * min(cfg.ssm_chunk, seq_len)]
+    return max(1, ACTIVATION_BYTES // (12 * seq_len * max(widths)))
+
+
+def token_feature_fn(cfg: ModelConfig):
+    """``feature_fn(params, tokens) -> (n, d_feat)`` fp32 for
+    ``AccumulationEngine``: pooled features of packed token rows, id 0
+    being padding (real ids are 1..V−1), so a row of padding gives zeros.
+    The rows run through the forward in blocks of at most
+    :func:`feature_block_rows`, one after another, so that a client's
+    activations fit whatever its rows."""
+
+    def fn(params, tokens: jax.Array) -> jax.Array:
+        n, S = tokens.shape
+        n_blocks = -(-n // feature_block_rows(cfg, S))
+        rows = -(-n // n_blocks)
+        padded = jnp.pad(tokens, ((0, n_blocks * rows - n), (0, 0)))
+
+        def block(t):
+            return extract_features(cfg, params, {"tokens": t, "mask": t != 0})
+
+        feats = jax.lax.map(block, padded.reshape(n_blocks, rows, S))
+        return feats.reshape(n_blocks * rows, -1)[:n]
+
+    return fn
 
 
 def prefill(

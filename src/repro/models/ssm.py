@@ -155,11 +155,23 @@ def _split_xbc(cfg: ModelConfig, xBC: jax.Array):
     return x, Bm, Cm
 
 
-def _gated_rmsnorm(y: jax.Array, z: jax.Array, scale: jax.Array) -> jax.Array:
+def _gated_rmsnorm(y: jax.Array, z: jax.Array, scale: jax.Array,
+                   groups: int = 1, eps: float = 1e-6) -> jax.Array:
+    """RMSNorm of y·silu(z), over all of d_inner or per group of
+    d_inner/groups channels (Nemotron-H)."""
     dt = y.dtype
     y = (y * jax.nn.silu(z)).astype(jnp.float32)
-    ms = jnp.mean(jnp.square(y), axis=-1, keepdims=True)
-    return (y * jax.lax.rsqrt(ms + 1e-6) * scale).astype(dt)
+    if groups == 1:
+        ms = jnp.mean(jnp.square(y), axis=-1, keepdims=True)
+        return (y * jax.lax.rsqrt(ms + eps) * scale).astype(dt)
+    yg = y.reshape(y.shape[:-1] + (groups, y.shape[-1] // groups))
+    ms = jnp.mean(jnp.square(yg), axis=-1, keepdims=True)
+    return ((yg * jax.lax.rsqrt(ms + eps)).reshape(y.shape) * scale).astype(dt)
+
+
+def _norm_args(cfg: ModelConfig) -> dict:
+    return {"groups": cfg.ssm_ngroups if cfg.ssm_group_norm else 1,
+            "eps": 1e-6 if cfg.norm_eps is None else cfg.norm_eps}
 
 
 def ssm_apply(
@@ -192,7 +204,7 @@ def ssm_apply(
     )
     y = y + xh * p["D"][None, None, :, None].astype(dt_)
     y = y.reshape(B, S, H * P)
-    y = _gated_rmsnorm(y, z, p["norm_scale"])
+    y = _gated_rmsnorm(y, z, p["norm_scale"], **_norm_args(cfg))
     out = y @ p["out_proj"].astype(dt_)
 
     cache = None
@@ -234,6 +246,6 @@ def ssm_decode_step(
     )
     y = jnp.einsum("bhpn,bhn->bhp", state, Ch) + xh * p["D"][None, :, None]
     y = y.reshape(B, H * P).astype(dt_)
-    y = _gated_rmsnorm(y, z, p["norm_scale"])
+    y = _gated_rmsnorm(y, z, p["norm_scale"], **_norm_args(cfg))
     out = (y @ p["out_proj"].astype(dt_))[:, None, :]
     return out, {"state": state, "conv": conv_state}

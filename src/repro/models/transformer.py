@@ -6,7 +6,11 @@ All six architecture families reduce to three stack shapes:
   ``jax.lax.scan`` over stacked layer parameters;
 * **hybrid** (RecurrentGemma) — a scan over homogeneous *super-blocks*
   (one (rec, rec, attn) pattern repetition each) plus an unrolled remainder;
-* **encoder-decoder** (Whisper) — two scans plus per-layer cross-attention.
+* **encoder-decoder** (Whisper) — two scans plus per-layer cross-attention;
+* **single-mixer** (Nemotron-H) — ``cfg.mixers``, one mixer a block
+  (``x + mixer(norm(x))``: M Mamba-2, E held-share MoE, * attention),
+  unrolled; each mixer runs under ``jax.named_scope`` ("mamba", "moe",
+  "attention"), so its ops carry the name in their metadata.
 
 Modes: ``train`` (causal, no cache), ``prefill`` (build KV/state caches),
 ``decode`` (one token, consume+update caches).  Remat (``jax.checkpoint``)
@@ -411,3 +415,43 @@ def apply_hybrid(
     if mode in ("prefill", "decode"):
         new_cache = {"super": new_super, "rem": new_rem}
     return x, new_cache, aux_total
+
+
+# ---------------------------------------------------------------------------
+# single-mixer stack (Nemotron-H): one mixer a block, unrolled
+# ---------------------------------------------------------------------------
+
+MIXER_SCOPE = {"M": "mamba", "E": "moe", "*": "attention"}
+
+
+def mixer_block_init(rng, cfg: ModelConfig, kind: str) -> dict:
+    if kind == "M":
+        mixer = ssm_mod.ssm_init(rng, cfg)
+    elif kind == "E":
+        mixer = moe_mod.held_moe_init(rng, cfg)
+    else:
+        mixer = attn_init(rng, cfg)
+    return {"norm": norm_init(cfg), MIXER_SCOPE[kind]: mixer}
+
+
+def mixer_stack_init(rng, cfg: ModelConfig) -> list:
+    keys = jax.random.split(rng, cfg.n_layers)
+    return [mixer_block_init(r, cfg, kind) for r, kind in zip(keys, cfg.mixers)]
+
+
+def apply_mixer_stack(cfg: ModelConfig, layers: list, x: jax.Array,
+                      real: Optional[jax.Array] = None) -> jax.Array:
+    """Causal forward of the single-mixer stack over whole sequences;
+    ``real`` (B, S) marks the real tokens for the MoE's routed experts."""
+    for kind, p in zip(cfg.mixers, layers):
+        h = norm_apply(cfg, p["norm"], x)
+        name = MIXER_SCOPE[kind]
+        with jax.named_scope(name):
+            if kind == "M":
+                y, _ = ssm_mod.ssm_apply(cfg, p[name], h)
+            elif kind == "E":
+                y, _ = moe_mod.held_moe_apply(cfg, p[name], h, real)
+            else:
+                y, _ = attn_apply(cfg, p[name], h)
+        x = x + y
+    return x
