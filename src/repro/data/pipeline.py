@@ -161,6 +161,22 @@ def _count_stats_rows(sizes: Sequence[int], capacity_rows: int) -> None:
     t.counter("stats_rows", kind="kernel").inc(sum(-(-n // BK) * BK for n in sizes))
 
 
+def _count_gram_rows(mask: np.ndarray) -> None:
+    """``gram_rows{kind}`` in the process-global registry: the real rows of
+    the packed waves, the packed rows (waves × slots × capacity), and the
+    rows of the BK-row blocks of each wave's flat design that hold a real
+    row, which the streaming Gram kernel sweeps."""
+    from repro.federated.telemetry import get_telemetry  # repro.federated imports this module
+
+    flat = mask.reshape(mask.shape[0], -1) > 0
+    pad = -flat.shape[1] % BK
+    live = np.pad(flat, ((0, 0), (0, pad))).reshape(flat.shape[0], -1, BK).any(2)
+    t = get_telemetry()
+    t.counter("gram_rows", kind="real").inc(int(flat.sum()))
+    t.counter("gram_rows", kind="packed").inc(mask.size)
+    t.counter("gram_rows", kind="kernel").inc(int(live.sum()) * BK)
+
+
 def pack_client_shards(
     clients: Sequence[Tuple[np.ndarray, np.ndarray]],
     clients_per_shard: int,
@@ -362,6 +378,7 @@ def pack_arrival_waves(
             labels[t, slot, :n_k] = y
             mask[t, slot, :n_k] = 1.0
             slot_ids[t, slot] = ids[i]
+    _count_gram_rows(mask)
     return PackedArrivals(
         inputs=inputs, labels=labels, mask=mask, client_ids=slot_ids
     )

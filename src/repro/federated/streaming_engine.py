@@ -72,6 +72,7 @@ from repro.federated.dist import (
 )
 from repro.kernels import chol_gram as chol_gram_kernel
 from repro.kernels import fed3r_stats as fed3r_stats_kernel
+from repro.kernels.chol_update import live_row_blocks
 from repro.sharding.hints import hint
 from repro.sharding.specs import replicated
 
@@ -236,7 +237,10 @@ class StreamingEngine(DistDispatchMixin):
             )
             S_local = S
         elif self._use_kernel():
-            G, dB = chol_gram_kernel(state.L, z, yh)
+            # the kernel sweeps only the row blocks that hold a real row
+            G, dB = chol_gram_kernel(
+                state.L, z, yh, *live_row_blocks(m.reshape(-1))
+            )
             b = state.b + dB
             S_local = None
         else:

@@ -53,7 +53,7 @@ def contract_rows(a: jax.Array, b: jax.Array) -> jax.Array:
     )
 
 
-def _tile(width: int, cap: int) -> Tuple[int, int]:
+def fit_tile(width: int, cap: int) -> Tuple[int, int]:
     """(tile, padded width): the fewest equal 128-multiple tiles of at most
     ``cap`` that cover ``width``."""
     lanes = -(-width // LANE)
@@ -103,8 +103,8 @@ def fed3r_stats_pallas(
     """
     n, d = Z.shape
     C = Y.shape[1]
-    bm, dp = _tile(d, TILE_M)
-    bn, ep = _tile(d + C, TILE_N)
+    bm, dp = fit_tile(d, TILE_M)
+    bn, ep = fit_tile(d + C, TILE_N)
     np_ = max(-(-n // BK), 1) * BK
     Zp = jnp.pad(Z, ((0, np_ - n), (0, dp - d)))
     Wp = jnp.pad(

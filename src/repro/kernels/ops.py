@@ -30,10 +30,15 @@ def fed3r_stats(
 
 
 def chol_gram(
-    L: jax.Array, Z: jax.Array, Y: jax.Array
+    L: jax.Array,
+    Z: jax.Array,
+    Y: jax.Array,
+    blocks: Optional[jax.Array] = None,
+    count: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Fused rank-n Cholesky-Gram update (G, B) = (L Lᵀ + ZᵀZ, ZᵀY)."""
-    return chol_gram_pallas(L, Z, Y, interpret=_interpret())
+    """Fused rank-n Cholesky-Gram update (G, B) = (L Lᵀ + ZᵀZ, ZᵀY) over
+    the live row blocks (``live_row_blocks`` of the mask; all by default)."""
+    return chol_gram_pallas(L, Z, Y, blocks, count, interpret=_interpret())
 
 
 def batched_chol_gram(

@@ -37,6 +37,8 @@ from repro.federated.streaming_engine import (
     batch_equivalent,
 )
 from repro.kernels import chol_gram
+from repro.kernels.chol_update import live_row_blocks
+from repro.kernels.fed3r_stats import BK
 from repro.kernels.ref import chol_gram_ref
 
 D, C = 24, 6
@@ -108,6 +110,36 @@ def test_arrival_packer_canonical_within_wave():
         np.testing.assert_array_equal(a, b)
 
 
+def test_arrival_packer_counts_gram_rows():
+    """``gram_rows``: real rows, waves × slots × capacity, and the rows of
+    the live BK-row blocks of each wave's flat design."""
+    from repro.federated.telemetry import Telemetry, set_telemetry
+
+    rng = np.random.default_rng(0)
+
+    def client(n):
+        return rng.normal(size=(n, D)).astype(np.float32), np.zeros(n, np.int32)
+
+    # capacity 192: wave 0's slots start at rows 0 and 192, wave 1's at 0
+    waves = [[client(5), client(BK + 1)], [client(192)], []]
+    prev = set_telemetry(Telemetry())
+    try:
+        pack_arrival_waves(waves, round_to=64)
+        t = set_telemetry(prev)
+    except BaseException:
+        set_telemetry(prev)
+        raise
+    rows = {k: t.counter("gram_rows", kind=k).value
+            for k in ("real", "packed", "kernel")}
+    assert rows == {
+        "real": 5 + BK + 1 + 192,
+        "packed": 3 * 2 * 192,
+        # wave 0: blocks 0 (rows 0-4), 1 and 2 (rows 192-320); wave 1:
+        # blocks 0 and 1; wave 2: none
+        "kernel": (3 + 2) * BK,
+    }
+
+
 def test_arrival_packer_validates():
     waves = _make_stream(3, 3, max_clients=2)
     with pytest.raises(ValueError):
@@ -125,20 +157,87 @@ def test_arrival_packer_validates():
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("d,n,C_", [(16, 30, 3), (65, 129, 7), (24, 7, 5)])
+def _factor(key, d):
+    A = jax.random.normal(key, (d, d), jnp.float32)
+    return jnp.linalg.cholesky(A @ A.T + jnp.eye(d))
+
+
+def _wave_design(key, d, sizes, N, C_, dtype):
+    """A packed wave's flat design (P·N rows): slot k's first sizes[k] rows
+    are real, the rest of its N rows masked to zero."""
+    k2, k3 = jax.random.split(key)
+    mask = (np.arange(N)[None, :] < np.asarray(sizes)[:, None]).reshape(-1)
+    m = jnp.asarray(mask, jnp.float32)[:, None]
+    Z = jax.random.normal(k2, (mask.size, d), jnp.float32) * m
+    Y = jax.nn.one_hot(jax.random.randint(k3, (mask.size,), 0, C_), C_) * m
+    return Z.astype(dtype), Y.astype(dtype), jnp.asarray(mask, jnp.float32)
+
+
+@pytest.mark.parametrize("d,n,C_", [
+    (16, 30, 3), (65, 129, 7), (24, 7, 5),
+    # (slot sizes, N): padded, empty and full slots; N = 3·64 or 5·64 (an
+    # odd multiple of 64), so 128-row blocks straddle two slots; d = 300
+    # (not a lane multiple, two row tiles), d + C = 307 and 356 (not one)
+    (300, ((5, 0, 192, 70), 192), 7),
+    (256, ((130, 0, 320), 320), 100),
+    (128, ((64, 1, 0, 64), 64), 128),
+])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_chol_gram_kernel_matches_oracle(d, n, C_, dtype, rng):
     k1, k2, k3 = jax.random.split(rng, 3)
-    A = jax.random.normal(k1, (d, d), jnp.float32)
-    L = jnp.linalg.cholesky(A @ A.T + jnp.eye(d))
-    Z = jax.random.normal(k2, (n, d), dtype)
-    Y = jax.nn.one_hot(jax.random.randint(k3, (n,), 0, C_), C_, dtype=dtype)
-    G, B = chol_gram(L, Z, Y)
+    L = _factor(k1, d)
+    if isinstance(n, int):  # every row real
+        Z = jax.random.normal(k2, (n, d), dtype)
+        Y = jax.nn.one_hot(jax.random.randint(k3, (n,), 0, C_), C_, dtype=dtype)
+        G, B = chol_gram(L, Z, Y)
+    else:  # a packed wave: the kernel sweeps its live row blocks
+        sizes, N = n
+        Z, Y, mask = _wave_design(k2, d, sizes, N, C_, dtype)
+        G, B = chol_gram(L, Z, Y, *live_row_blocks(mask))
+        Z, Y, n = Z[mask > 0], Y[mask > 0], sum(sizes)
     Gr, Br = chol_gram_ref(L, Z, Y)
     tol = 5e-2 if dtype == jnp.bfloat16 else 1e-4
     np.testing.assert_allclose(np.asarray(G), np.asarray(Gr), rtol=tol, atol=tol * n)
     np.testing.assert_allclose(np.asarray(B), np.asarray(Br), rtol=tol, atol=tol * n)
     assert G.dtype == jnp.float32
+
+
+def test_chol_gram_kernel_never_reads_dead_row_blocks(rng):
+    """NaN in every 128-row block that holds no real row: the sample sweep
+    never reads those blocks, so G and B still match the real rows."""
+    k1, k2 = jax.random.split(rng)
+    d, C_, N = 136, 9, 192
+    sizes = (3, 0, 0, 0, 150, 0, 0, 0, 0, 64)  # live blocks 0, 6, 7, 13
+    Z, Y, mask = _wave_design(k2, d, sizes, N, C_, jnp.float32)
+    blocks, count = live_row_blocks(mask)
+    dead = np.ones(mask.size // BK, bool)
+    dead[np.asarray(blocks)[: int(count[0])]] = False
+    assert np.flatnonzero(~dead).tolist() == [0, 6, 7, 13]
+    rows = jnp.asarray(np.repeat(dead, BK))[:, None]
+    Zn, Yn = jnp.where(rows, jnp.nan, Z), jnp.where(rows, jnp.nan, Y)
+    L = _factor(k1, d)
+    G, B = chol_gram(L, Zn, Yn, blocks, count)
+    Gr, Br = chol_gram_ref(L, Z[mask > 0], Y[mask > 0])
+    np.testing.assert_allclose(np.asarray(G), np.asarray(Gr), rtol=1e-4, atol=1e-2)
+    np.testing.assert_allclose(np.asarray(B), np.asarray(Br), rtol=1e-4, atol=1e-2)
+
+
+def test_chol_gram_kernel_ignores_factor_upper_triangle(rng):
+    """Garbage above L's diagonal gives the G of its ``tril``: L is read as
+    lower-triangular, which is what lets the factor sweep stop at each row
+    tile's last row."""
+    k1, k2, k3 = jax.random.split(rng, 3)
+    d = 300  # two row tiles of 256
+    L = _factor(k1, d)
+    junk = jnp.triu(jax.random.normal(k3, (d, d)) * 1e3, 1)
+    Z, Y, mask = _wave_design(k2, d, (40, 7), 64, 5, jnp.float32)
+    live = live_row_blocks(mask)
+    G, B = chol_gram(L + junk, Z, Y, *live)
+    G0, B0 = chol_gram(L, Z, Y, *live)
+    np.testing.assert_array_equal(np.asarray(G), np.asarray(G0))
+    np.testing.assert_array_equal(np.asarray(B), np.asarray(B0))
+    Gr, _ = chol_gram_ref(L, Z, Y)
+    np.testing.assert_allclose(np.asarray(G), np.asarray(Gr), rtol=1e-4, atol=1e-2)
 
 
 def test_chol_gram_kernel_handles_empty_arrival_batch():
@@ -150,7 +249,10 @@ def test_chol_gram_kernel_handles_empty_arrival_batch():
 
 
 def test_engine_kernel_path_matches_xla_path():
-    packed = pack_arrival_waves(_make_stream(4, 5))
+    # slots padded to 192 rows, so most of each wave's row blocks are dead
+    packed = pack_arrival_waves(_make_stream(4, 5), round_to=64, max_n=192)
+    _, count = live_row_blocks(jnp.asarray(packed.mask[0].reshape(-1)))
+    assert 0 < int(count[0]) < packed.mask[0].size // BK
     xla = StreamingEngine(_cfg(use_kernel=False))
     ker = StreamingEngine(_cfg(use_kernel=True))
     s1, _ = xla.absorb(xla.init(D), packed)

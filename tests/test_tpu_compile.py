@@ -14,7 +14,11 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.chol_update import batched_chol_gram_pallas, chol_gram_pallas
+from repro.kernels.chol_update import (
+    batched_chol_gram_pallas,
+    chol_gram_pallas,
+    live_row_blocks,
+)
 from repro.kernels.fed3r_stats import fed3r_stats_pallas
 from repro.kernels.quant import TILE, dequant_acc_pallas, quantize_tiles_pallas
 
@@ -78,10 +82,30 @@ def test_fed3r_stats_live_extent_compiles(one_chip, capacity):
 
 
 def test_chol_gram_compiles(one_chip):
-    compiled = chol_gram_pallas.lower(
+    """The streaming engine's call: a wave of 10 slots × 640 rows, its row
+    blocks read live from the mask."""
+    n = 10 * 640
+
+    def wave_gram(L, z, yh, mask):
+        return chol_gram_pallas(L, z, yh, *live_row_blocks(mask), interpret=False)
+
+    compiled = jax.jit(wave_gram).lower(
         _spec((D, D), jnp.float32, one_chip),
-        _spec((N, D), jnp.float32, one_chip),
-        _spec((N, C), jnp.float32, one_chip),
+        _spec((n, D), jnp.float32, one_chip),
+        _spec((n, C), jnp.float32, one_chip),
+        _spec((n,), jnp.float32, one_chip),
+    ).compile()
+    _assert_kernel(compiled)
+
+
+def test_chol_gram_compiles_wide_features(one_chip):
+    """Random-feature widths: G's full-width row tiles outgrow the default
+    VMEM limit, and the kernel asks for what its tiles need."""
+    d, n = 8192, 1024
+    compiled = chol_gram_pallas.lower(
+        _spec((d, d), jnp.float32, one_chip),
+        _spec((n, d), jnp.float32, one_chip),
+        _spec((n, C), jnp.float32, one_chip),
         interpret=False,
     ).compile()
     _assert_kernel(compiled)
