@@ -6,6 +6,7 @@ same one-class-per-client federation.
 
     PYTHONPATH=src python examples/fed3r_vs_fedavg.py
 """
+from dataclasses import replace
 
 from repro.configs.base import Fed3RConfig, FederatedConfig
 from repro.data import make_federated_features
@@ -28,8 +29,9 @@ for name, rf in (("fed3r", 0), ("fed3r-rf", 1024)):
     f3 = Fed3RConfig(n_classes=C, n_random_features=rf, rff_sigma=12.0)
     fc = FederatedConfig(n_clients=K, clients_per_round=10, n_rounds=100)
     _, _, h = run_fed3r(fed, test.features, test.labels, f3, fc, eval_every=1)
-    up = cm.comm_per_client(name)["up"] * 4
-    fl = cm.comp_per_client(name, avg_nk)
+    row_cm = replace(cm, D=rf)  # the RF rows upload D-wide statistics
+    up = row_cm.comm_per_client(name)["up"] * 4
+    fl = row_cm.comp_per_client(name, avg_nk)
     print(f"{name:14s} {h.rounds[-1]:7d} {h.accuracy[-1]:9.4f} "
           f"{up/1e6:11.1f}MB {fl/1e9:13.2f}")
 

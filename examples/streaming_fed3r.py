@@ -7,11 +7,9 @@ recursive-least-squares formulation of §4.1.  This example runs the
 arrival timeline through the STREAMING ENGINE
 (repro.federated.streaming_engine): all T waves fold through one jitted
 scan (1 dispatch instead of T), carrying the Cholesky factor of A + λI and
-refreshing the served W by two triangular solves.
-
-It also demos WHY the engine replaced the subtractive Woodbury loop: at
-small λ the legacy path's carried A⁻¹ cancels catastrophically in fp32,
-while the factored state tracks the batch re-solve to machine precision.
+refreshing the served W by two triangular solves.  At small λ the
+factored state tracks the batch re-solve to fp32 round-off: every update
+adds to the factor, none subtracts from a carried inverse.
 
     PYTHONPATH=src python examples/streaming_fed3r.py
 """
@@ -23,7 +21,6 @@ from repro.core import fed3r
 from repro.data.pipeline import pack_arrival_waves
 from repro.data.synthetic import make_feature_dataset
 from repro.federated.streaming_engine import (
-    ReferenceArrivalLoop,
     StreamConfig,
     StreamingEngine,
     batch_equivalent,
@@ -51,18 +48,12 @@ cfg = StreamConfig(n_classes=C, ridge_lambda=LAM, refresh_every=1)
 engine = StreamingEngine(cfg)
 state, trace = engine.absorb(engine.init(D), packed)  # T waves, ONE dispatch
 
-legacy = ReferenceArrivalLoop(cfg)  # T subtractive Woodbury dispatches
-W_legacy = legacy.classifier(legacy.absorb(legacy.init(D), packed))
-
 print(f"{packed.n_waves} waves, {packed.n_samples} samples: "
-      f"engine={engine.dispatches} dispatch, legacy loop={legacy.dispatches}")
+      f"engine={engine.dispatches} dispatch")
 print(f"served accuracy: {float(fed3r.accuracy(state.W, test_x, test_y)):.4f} "
       f"(refresh-on-arrival; staleness always 0)")
 
 W_batch, _ = batch_equivalent(packed, cfg)
 err_fac = float(jnp.max(jnp.abs(state.W - W_batch)))
-err_leg = float(jnp.max(jnp.abs(W_legacy - W_batch)))
-print(f"\nmax |W − W_batch|   factored engine: {err_fac:.2e}   "
-      f"legacy Woodbury: {err_leg:.2e}")
-print("(the subtractive fp32 path visibly diverges at small λ; "
-      "the factored form is exact to fp32 round-off)")
+print(f"\nmax |W − W_batch| at λ={LAM}: {err_fac:.2e} "
+      "(the factored form is exact to fp32 round-off)")

@@ -2,7 +2,7 @@
 
 The paper uses an ImageNet-pretrained MobileNetV2 whose feature space is
 d=1280.  Offline we cannot ship MobileNetV2 weights, so the FED3R-family
-benchmarks use either (a) raw synthetic feature vectors of d=1280 (data-level
+drivers use either (a) raw synthetic feature vectors of d=1280 (data-level
 φ) or (b) this small dense transformer with d_model=1280 as a stand-in
 extractor for the end-to-end FED3R+FT drivers.
 """

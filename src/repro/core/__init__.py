@@ -2,7 +2,6 @@
 from repro.core import calibration, fed3r, ncm, probe, random_features  # noqa: F401
 from repro.core.fed3r import (  # noqa: F401
     Fed3RFactored,
-    Fed3ROnline,
     Fed3RStats,
     aggregate_mesh,
     client_stats,

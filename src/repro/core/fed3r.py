@@ -8,9 +8,8 @@ pure function over a tiny ``Fed3RStats`` pytree so the same code runs:
   ("pod", "data") mesh axes — the paper's client→server aggregation mapped
   onto an all-reduce; exactness of the sum *is* the paper's immunity claim),
 * in **streaming/online** mode (``Fed3RFactored`` — the recursive
-  least-squares formulation of Eq. (3) kept in Cholesky-factored form;
-  the subtractive Sherman–Morrison–Woodbury path ``woodbury_update`` is
-  retained as a deprecated compat path),
+  least-squares formulation of Eq. (3) kept in Cholesky-factored form,
+  so every update adds and none subtracts),
 * in **multi-tenant personalized** mode (``personalized_solution`` /
   ``batched_personalized_solution`` — per-client heads
   W_k = (A + α_k·A_k + λI)⁻¹(b + α_k·b_k) as rank-n updates of the shared
@@ -24,7 +23,6 @@ Solve (Eq. 4):  W* = (A + λI)⁻¹ b, then per-class column normalization.
 """
 from __future__ import annotations
 
-import warnings
 from typing import NamedTuple, Optional, Sequence, Union
 
 import jax
@@ -165,7 +163,7 @@ class Fed3RFactored(NamedTuple):
 
     The numerically stable recursive-least-squares formulation of Eq. (3):
     every arrival performs the ADDITIVE rank-n update L ← chol(L Lᵀ + ZᵀZ)
-    (no subtraction, hence no fp32 cancellation — contrast ``Fed3ROnline``),
+    (no subtraction, hence no fp32 cancellation of a carried inverse),
     and the solution W = (A + λI)⁻¹ b is two triangular solves against L.
     This is the state carried by the streaming arrival engine
     (:mod:`repro.federated.streaming_engine`) and the shared base every
@@ -289,89 +287,3 @@ def batched_personalized_solution(
             state, Fed3RStats(A=A, b=b, n=jnp.zeros((), jnp.float32)), a, normalize
         )
     )(A_k, b_k, jnp.asarray(alphas, jnp.float32))
-
-
-# ---------------------------------------------------------------------------
-# Deprecated: subtractive Sherman–Morrison–Woodbury compat path
-# ---------------------------------------------------------------------------
-
-
-class Fed3ROnline(NamedTuple):
-    """DEPRECATED online RR state carrying A⁻¹ directly.
-
-    With λ ≪ tr(A)/d the initial A⁻¹ = I/λ is orders of magnitude larger
-    than the converged inverse, so the subtractive Woodbury update suffers
-    catastrophic cancellation in fp32 (observed ~1e-2 max-abs error on W at
-    λ = 1e-2 where :class:`Fed3RFactored` stays ≤ 1e-6).  Kept only as a
-    compat path; use ``init_factored``/``factored_update`` instead.
-    """
-
-    Ainv: jax.Array  # (d, d) fp32 — (A + λI)⁻¹
-    b: jax.Array  # (d, C)
-
-
-# fp32 cancellation becomes visible once 1/λ dwarfs the converged inverse;
-# below this λ the legacy path is known-bad even at modest sample counts
-_SMALL_LAMBDA = 0.1
-
-
-def _warn_legacy_woodbury(ridge_lambda: Optional[float] = None) -> None:
-    hazard = (
-        " At small ridge_lambda the subtractive update CANCELS"
-        " catastrophically in fp32 — expect a visibly wrong W."
-        if ridge_lambda is not None and ridge_lambda < _SMALL_LAMBDA
-        else ""
-    )
-    warnings.warn(
-        "Fed3ROnline/woodbury_update is deprecated: the subtractive Woodbury"
-        " update is numerically unstable in fp32. Use the factored state"
-        " (init_factored/factored_update/factored_solution) or the streaming"
-        " engine (repro.federated.streaming_engine)." + hazard,
-        DeprecationWarning,
-        stacklevel=3,
-    )
-
-
-def init_online(d: int, n_classes: int, ridge_lambda: float) -> Fed3ROnline:
-    _warn_legacy_woodbury(ridge_lambda)
-    return Fed3ROnline(
-        Ainv=jnp.eye(d, dtype=jnp.float32) / ridge_lambda,
-        b=jnp.zeros((d, n_classes), jnp.float32),
-    )
-
-
-def woodbury_update(state: Fed3ROnline, features: jax.Array, labels: jax.Array) -> Fed3ROnline:
-    """DEPRECATED rank-n update with a new client's batch Z (n, d):
-
-    (A + ZᵀZ)⁻¹ = A⁻¹ − A⁻¹Zᵀ (I + Z A⁻¹ Zᵀ)⁻¹ Z A⁻¹
-
-    The subtraction is the fp32 hazard; prefer :func:`factored_update`.
-    """
-    Z = features.astype(jnp.float32)
-    n = Z.shape[0]
-    C = state.b.shape[1]
-    AiZt = state.Ainv @ Z.T  # (d, n)
-    K = jnp.eye(n, dtype=jnp.float32) + Z @ AiZt  # (n, n)
-    L = jax.scipy.linalg.cho_factor(K, lower=True)
-    Ainv = state.Ainv - AiZt @ jax.scipy.linalg.cho_solve(L, AiZt.T)
-    b = state.b + Z.T @ jax.nn.one_hot(labels, C, dtype=jnp.float32)
-    return Fed3ROnline(Ainv=Ainv, b=b)
-
-
-def online_solution(
-    state: Union[Fed3RFactored, Fed3ROnline], normalize: bool = True
-) -> jax.Array:
-    """Solution of either online state; routes through the factored path.
-
-    Given a :class:`Fed3RFactored` this IS :func:`factored_solution` (two
-    triangular solves).  The legacy :class:`Fed3ROnline` branch is kept for
-    compatibility and warns: its W inherits the accumulated cancellation
-    error of the carried A⁻¹.
-    """
-    if isinstance(state, Fed3RFactored):
-        return factored_solution(state, normalize)
-    _warn_legacy_woodbury()
-    W = state.Ainv @ state.b
-    if normalize:
-        W = normalize_columns(W)
-    return W

@@ -39,7 +39,6 @@ from repro.federated.async_engine import (  # noqa: F401
     run_chaos_timeline,
 )
 from repro.federated.streaming_engine import (  # noqa: F401
-    ReferenceArrivalLoop,
     StreamConfig,
     StreamState,
     StreamingEngine,

@@ -26,14 +26,14 @@ draws seeded, replayable tenant-attributed query traces under the
 bounded-Zipf popularity skew of the production cross-device regime — a
 tiny head of hot tenants dominating a long cold tail — which is what the
 slot-serving engine's cache/eviction policies are exercised against
-(``benchmarks/bench_serving.py``, ``repro.launch.serve_heads``).
+(``repro.launch.serving_engine``, ``repro.launch.serve_heads``).
 
 The UPLOAD side — what the network does to the statistics a client sends —
 is the chaos-mode fault injector (:class:`ChaosSpec`,
 :func:`chaos_round_events`, :func:`chaos_timeline`): seeded, replayable
 drop/duplicate/reorder/delay schedules consumed by the asynchronous round
-engine (:mod:`repro.federated.async_engine`) and replayed by the chaos CI
-gate (``benchmarks/chaos_replay.py``).
+engine (:mod:`repro.federated.async_engine`); eight seeded schedules are
+replayed by ``tests/test_async.py::test_chaos_schedule_async_bitwise_equals_barrier``.
 """
 from __future__ import annotations
 
@@ -189,8 +189,8 @@ def zipf_traffic(
 # (:mod:`repro.federated.async_engine`) consumes: seeded, replayable
 # schedules that DROP uploads (forcing retransmits), DUPLICATE deliveries,
 # REORDER concurrent arrivals, and DELAY stragglers — the four faults the
-# chaos CI gate replays (`benchmarks/chaos_replay.py`) while asserting the
-# folded classifier is bitwise unchanged versus the synchronous barrier.
+# chaos tests replay while asserting the folded classifier is bitwise
+# unchanged versus the synchronous barrier.
 
 
 class UploadEvent(NamedTuple):

@@ -62,10 +62,8 @@ that:
 The fault model driving all of this lives in
 :mod:`repro.federated.arrivals` (:class:`~repro.federated.arrivals.
 ChaosSpec` seeded drop/duplicate/reorder/delay schedules);
-``benchmarks/chaos_replay.py`` is the CI gate replaying eight of them and
-failing on any W divergence, and ``benchmarks/bench_async.py`` measures
-the round-completion speedup of closing at the deadline instead of
-waiting for the straggler tail.
+``tests/test_async.py::test_chaos_schedule_async_bitwise_equals_barrier``
+replays eight of them and fails on any W divergence.
 """
 from __future__ import annotations
 
@@ -667,7 +665,7 @@ def run_chaos_timeline(
     synchronous baseline (``cfg.synchronous``) instead BARRIERS: each
     round's completion is the straggler's arrival, and the next round
     starts only then — the makespan gap between the two is what
-    ``benchmarks/bench_async.py`` prices.
+    :meth:`repro.federated.costs.CostModel.straggler_tail` prices.
     """
     cfg = engine.cfg
     interval = cfg.deadline if interval is None else interval

@@ -462,9 +462,10 @@ class CostModel:
         The asynchronous engine closes at ``deadline_s`` regardless (late
         uploads keep merging under the staleness bound), so its completion
         is min(deadline, tail).  The returned ``speedup`` is the analytic
-        counterpart of the measured ``benchmarks/bench_async.py`` figure;
-        wire bytes are unchanged (the same uploads move, just later), so
-        this prices TIME, not bytes.
+        counterpart of the simulated makespans of
+        :func:`repro.federated.async_engine.run_chaos_timeline`; wire bytes
+        are unchanged (the same uploads move, just later), so this prices
+        TIME, not bytes.
         """
         if clients_per_round < 1:
             raise ValueError(

@@ -252,9 +252,9 @@ class DistContext:
     resettable — plus the aggregation backend (:meth:`all_reduce`) and
     program construction (:meth:`jit`).  Engines keep their
     ``.dispatches`` attribute as a property proxying this counter
-    (:class:`DistDispatchMixin`), so benchmarks keep working unchanged;
-    the CI regression gate reads the SAME cells back out of the
-    ``telemetry_*.json`` snapshots, so the two can't diverge.
+    (:class:`DistDispatchMixin`), and :func:`repro.federated.telemetry
+    .dispatch_summary` reads the SAME cells back out of a snapshot, so
+    the two can't diverge.
     """
 
     def __init__(
@@ -362,8 +362,8 @@ class DistContext:
 class DistDispatchMixin:
     """The engines' public ``.dispatches`` counter, proxied onto the owned
     :class:`DistContext` (``self.dist``) — which in turn homes it in the
-    telemetry registry as ``engine_dispatches_total`` — kept settable
-    because the benchmarks reset it between timed sections."""
+    telemetry registry as ``engine_dispatches_total`` — kept settable so a
+    caller can reset it between sections."""
 
     dist: DistContext
 

@@ -33,9 +33,9 @@ K personalized heads solve in ONE jitted dispatch instead of K re-solves:
 :class:`ReferencePersonalizedLoop` preserves the per-client shape — one
 jitted global solve plus one jitted re-solve per client (K+1 dispatches
 for a K-head cohort) — as the dispatch baseline and the parity oracle
-(``benchmarks/bench_personalize.py``).  The multi-tenant serving layer
-(LRU head cache over a live arrival stream) is
-:mod:`repro.launch.serve_heads`.
+(``tests/test_personalization.py::test_engine_matches_reference_loop_at_same_alphas``).
+The multi-tenant serving layer (LRU head cache over a live arrival stream)
+is :mod:`repro.launch.serve_heads`.
 """
 from __future__ import annotations
 
@@ -298,8 +298,8 @@ class ReferencePersonalizedLoop:
     One global ``factored_solution`` (what a non-personalized server would
     serve) plus one per-client re-solve each — client statistics and the
     d×d refactorization re-dispatched per tenant.  Kept as the dispatch
-    baseline and the numerical parity oracle the batched engine is measured
-    against (``benchmarks/bench_personalize.py``).
+    baseline and the numerical parity oracle the batched engine is checked
+    against.
     """
 
     def __init__(self, cfg: PersonalizeConfig):

@@ -27,8 +27,9 @@ step, and the Scaffold control-variate scatter — inside ONE jitted
   then cross-pod DCN), the server step replicated — still ONE dispatch.
 
 K clients/round therefore cost 1 dispatch instead of K+1
-(``benchmarks/bench_rounds.py``); :class:`ReferenceLoop` preserves the
-seed-era per-client shape as the parity/benchmark baseline.
+(``tests/test_round_engine.py::test_round_engine_matches_reference_loop``);
+:class:`ReferenceLoop` preserves the per-client shape as the parity
+baseline.
 """
 from __future__ import annotations
 

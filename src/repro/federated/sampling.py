@@ -6,7 +6,7 @@ The paper (§4.3, Fig. 3, App. I) distinguishes:
   exactly once; convergence is exact after ⌈K/κ⌉ rounds;
 * **with replacement** — classical FL sampling; the paper's worst-case
   analysis connects rounds-to-coverage to the Batch Coupon Collector problem
-  (Table 7), reproduced in benchmarks/bench_coupon.py.
+  (Table 7), reproduced in examples/paper/bench_coupon.py.
 
 :func:`sample_round` is the STATELESS core: the cohort of round ``rnd`` is a
 pure function of (n_clients, per_round, rnd, seed, replacement), so a

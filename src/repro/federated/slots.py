@@ -22,8 +22,7 @@ victims rank by ``(last_used, hits)`` lexicographically — least-recently
 served first, ties broken by lifetime popularity — so a Zipf-hot tenant
 survives a sweep of one-shot cold tenants even when their recency is
 newer.  :class:`TenantUniverse` maps a simulated millions-of-tenants id
-space onto a base federation's client data for benchmark-scale traffic
-(``benchmarks/bench_serving.py``).
+space onto a base federation's client data for serving-scale traffic.
 """
 from __future__ import annotations
 

@@ -1,16 +1,15 @@
-"""Streaming FED3R arrival engine — batched stable Woodbury + live serving.
+"""Streaming FED3R arrival engine — factored rank-n updates + live serving.
 
 The third engine of the triptych (batch statistics → rounds → streaming):
 the paper's recursive-least-squares formulation (Eq. 3) and its §6 future
-work — clients arriving over time with new data — promoted from a
-per-arrival Python loop over the fp32-hazardous subtractive
-``woodbury_update`` to a first-class arrival-driven runtime:
+work — clients arriving over time with new data — as an arrival-driven
+runtime:
 
 * the timeline arrives as a :class:`repro.data.pipeline.PackedArrivals`
   (padded ``(n_waves, clients_per_wave, max_n, ...)`` arrays with masks);
 * ALL T waves fold through ONE jitted ``lax.scan`` with donated state —
-  1 dispatch for the whole stream instead of the loop's T
-  (``benchmarks/bench_streaming.py``);
+  1 dispatch for the whole stream
+  (``tests/test_streaming.py::test_streaming_matches_batch_solve_at_small_lambda``);
 * the carried state is the numerically stable FACTORED form
   (:class:`repro.core.fed3r.Fed3RFactored` semantics): the lower Cholesky
   factor L of A + λI, advanced per wave by the additive rank-n update
@@ -45,13 +44,10 @@ identical to today.
 Exactness: each wave's clients are canonically packed (sorted by id), so
 the folded state — and the final W — is bitwise invariant to the
 presentation order of concurrent arrivals; across waves the stream order
-IS the semantics.  :class:`ReferenceArrivalLoop` preserves the seed-era
-per-arrival shape (one jitted subtractive Woodbury dispatch per wave) as
-the dispatch baseline and the numerical foil.
+IS the semantics.
 """
 from __future__ import annotations
 
-import warnings
 from dataclasses import dataclass, field
 from typing import Any, Callable, NamedTuple, Optional, Tuple
 
@@ -410,45 +406,6 @@ class StreamingEngine(DistDispatchMixin):
     def classifier(self, state: StreamState) -> jax.Array:
         """The currently SERVED classifier (possibly stale, by policy)."""
         return state.W
-
-
-class ReferenceArrivalLoop:
-    """The seed-era per-arrival path: one jitted subtractive Woodbury
-    dispatch per wave (T dispatches for a T-wave stream).
-
-    Kept as the dispatch-count baseline the streaming engine is measured
-    against and as the numerical foil: at small λ its carried A⁻¹ cancels
-    catastrophically in fp32 (``benchmarks/bench_streaming.py`` reports the
-    divergence).  Padding rows are zero in the packed arrays, hence exact
-    no-ops in the Woodbury algebra too.
-    """
-
-    def __init__(self, cfg: StreamConfig):
-        self.cfg = cfg
-        self.dispatches = 0
-        self._update = jax.jit(fed3r.woodbury_update)
-
-    def init(self, d: int) -> fed3r.Fed3ROnline:
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore", DeprecationWarning)
-            return fed3r.init_online(d, self.cfg.n_classes, self.cfg.ridge_lambda)
-
-    def absorb(
-        self, state: fed3r.Fed3ROnline, packed: PackedArrivals
-    ) -> fed3r.Fed3ROnline:
-        for t in range(packed.n_waves):
-            x = packed.inputs[t]
-            flat = x.reshape((x.shape[0] * x.shape[1],) + x.shape[2:])
-            state = self._update(
-                state, jnp.asarray(flat), jnp.asarray(packed.labels[t].reshape(-1))
-            )
-            self.dispatches += 1
-        return state
-
-    def classifier(self, state: fed3r.Fed3ROnline) -> jax.Array:
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore", DeprecationWarning)
-            return fed3r.online_solution(state, self.cfg.normalize)
 
 
 def batch_equivalent(

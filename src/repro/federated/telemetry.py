@@ -8,8 +8,8 @@ engine's hand-rolled ``stage_s`` wall-time dicts, shed/drop tallies,
 through:
 
 * **Counters / gauges** — labeled, plain-Python numeric cells.  Counters
-  are monotone by convention but keep a ``set`` method because the
-  benchmarks reset dispatch counts between timed sections
+  are monotone by convention but keep a ``set`` method so a caller can
+  reset dispatch counts between sections
   (``engine.dispatches = 0`` still works through the back-compat property
   on :class:`repro.federated.dist.DistDispatchMixin`).
 * **Histograms** — log-bucketed (HDR-style): bucket edges grow by
@@ -93,7 +93,7 @@ class Counter:
     """Labeled numeric total (int or float, e.g. wire bytes).
 
     Plain Python arithmetic — safe on hot paths.  ``set`` exists for the
-    benchmarks' reset-between-timed-sections idiom.
+    ``engine.dispatches = 0`` reset idiom.
     """
 
     __slots__ = ("name", "labels", "value")
@@ -327,7 +327,7 @@ class Telemetry:
 
     def next_instance(self, kind: str) -> int:
         """Monotone per-kind instance ids, so N same-type engines own N
-        distinct counter series (the benchmarks construct several serving
+        distinct counter series (a process may hold several serving
         engines and reset/read each one's dispatches independently)."""
         with self._lock:
             n = self._instances.get(kind, 0)
@@ -451,16 +451,13 @@ class Telemetry:
     def merge_snapshot(self, snap: dict) -> None:
         """Fold another registry's :meth:`snapshot` into this one.
 
-        The multi-process aggregation path: subprocess benchmark workers
-        (``bench_scaleout.py``, ``bench_tiers.py`` — each pinned to its own
-        simulated device count) snapshot their own registries and the
-        parent merges them, so dispatch counters and flight-recorder
-        events survive the process boundary and ``check_regression.py``
-        reads scale-out dispatches from ``telemetry_*.json`` like every
-        other bench.  Counters ADD, gauges take the incoming last-write,
-        histograms merge bucket-wise (quantiles stay within one bucket of
-        the union stream), events append in arrival order (ring bounds
-        still apply).
+        The multi-process aggregation path: subprocess workers (each
+        pinned to its own simulated device count) snapshot their own
+        registries and the parent merges them, so dispatch counters and
+        flight-recorder events survive the process boundary.  Counters
+        ADD, gauges take the incoming last-write, histograms merge
+        bucket-wise (quantiles stay within one bucket of the union
+        stream), events append in arrival order (ring bounds still apply).
         """
         for c in snap.get("counters", ()):
             self.counter(c["name"], **c.get("labels", {})).inc(c["value"])
@@ -550,8 +547,7 @@ def dispatch_summary(snapshot: dict) -> Dict[str, int]:
     """Per-engine host→device dispatch totals from a snapshot.
 
     Sums the per-instance ``engine_dispatches_total`` series by engine
-    name — the exact numbers ``benchmarks/check_regression.py`` gates, so
-    the CI gate and the telemetry layer cannot diverge.
+    name.
     """
     out: Dict[str, int] = {}
     for c in snapshot.get("counters", []):

@@ -14,9 +14,7 @@ The lines above MUST run before any jax import (jax locks the device
 count on first init) — which is why this module must only ever be executed
 as a script/module entry point, never imported by tests.  The simulated
 host-device count defaults to the full multi-pod mesh (512) and can be
-overridden with ``REPRO_DRYRUN_DEVICES=N`` for smaller scale-out dry runs
-(the weak-scaling bench ``benchmarks/bench_scaleout.py`` drives the same
-flag per worker subprocess at N ∈ {1, 4, 8}).
+overridden with ``REPRO_DRYRUN_DEVICES=N`` for smaller scale-out dry runs.
 
 Per combination, TWO kinds of compile:
 

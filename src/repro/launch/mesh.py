@@ -19,8 +19,8 @@ this module never touches jax device state — required because the dry-run
 must set ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` before the
 first jax device query, while smoke tests must keep seeing 1 device.  The
 host meshes (``make_host_mesh``) build the same axis layouts over however
-many (possibly simulated) local devices exist, so tests and the weak-
-scaling bench (``benchmarks/bench_scaleout.py``) exercise the exact
+many (possibly simulated) local devices exist, so the sharded tests
+(``tests/test_dist.py``, ``tests/test_tiers.py``) exercise the exact
 production code paths.
 """
 from __future__ import annotations

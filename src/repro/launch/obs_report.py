@@ -1,11 +1,10 @@
 """Render a telemetry snapshot (``telemetry_*.json``) for humans.
 
-``benchmarks/run.py`` persists one :meth:`repro.federated.telemetry
-.Telemetry.snapshot` per benchmark module (uploaded as a CI artifact);
-this thin CLI turns a snapshot — or the live process-global registry of
-an imported module — into a readable report: per-engine dispatch totals,
-counters/gauges, span p50/p99/p999, and the tail of the flight-recorder
-event ring.
+A snapshot is what :meth:`repro.federated.telemetry.Telemetry.snapshot`
+returns, saved as JSON; this thin CLI turns a snapshot — or the live
+process-global registry of an imported module — into a readable report:
+per-engine dispatch totals, counters/gauges, span p50/p99/p999, and the
+tail of the flight-recorder event ring.
 
 Usage:
     PYTHONPATH=src python -m repro.launch.obs_report telemetry_serving.json

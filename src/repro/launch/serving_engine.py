@@ -31,8 +31,7 @@ in-flight batching of queries across tenants between solve ticks
 (``max_batch`` caps a tick's serve width so traffic bursts spread over
 ticks instead of unbounded batches).  Stage wall-times and dispatch
 counters are tracked per stage, decode-microbenchmark style
-(``benchmarks/bench_serving.py`` reports p50/p99 latency and sustained
-QPS under Zipf traffic against the synchronous LRU path).
+(``tests/test_serving.py::test_slot_engine_one_dispatch_per_stage``).
 
 Observability (:mod:`repro.federated.telemetry`): every stage runs under
 a span (``span_seconds{engine=serving, stage=tick/solve, ...}``), the
@@ -200,7 +199,7 @@ class ServingEngine:
         self._serve = jax.jit(self._serve_impl)
 
     # counters proxied onto their telemetry cells — `self.hits += 1` and the
-    # benchmarks' reset-to-zero idiom keep working unchanged
+    # reset-to-zero idiom keeps working unchanged
     def _cell(name: str):  # noqa: N805 — descriptor factory, not a method
         def _get(self) -> int:
             return int(self._cells[name].value)

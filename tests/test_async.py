@@ -30,10 +30,12 @@ from repro.federated.arrivals import (
     timeline_from_json,
     timeline_to_json,
 )
+from repro.data.pipeline import make_federated_features
 from repro.federated.async_engine import (
     AsyncConfig,
     AsyncRoundEngine,
     ClientHealth,
+    client_payloads,
     run_adaptive_rounds,
     run_chaos_timeline,
 )
@@ -111,6 +113,86 @@ def test_chaos_parity_bitwise_per_fault_type(fault):
     np.testing.assert_array_equal(np.asarray(sa.L), np.asarray(ss.L))
     if fault in ("duplicate", "all"):
         assert ra["duplicates"] > 0  # dedup actually exercised
+
+
+# Seeded chaos schedules: 4 fault profiles x 2 seeds.  Each profile stresses
+# one fault mode; rto/max_attempts bound the retransmit tail so every upload
+# lands inside the staleness window (exact-once delivery, the precondition
+# of the parity claim).
+CHAOS_D, CHAOS_C = 32, 8
+CHAOS_N_CLIENTS = 16
+CHAOS_COHORT = 6
+CHAOS_N_ROUNDS = 6
+CHAOS_DEADLINE = 1.0
+CHAOS_STALENESS = 4
+CHAOS_PROFILES = {
+    "drop_heavy": ChaosSpec(drop=0.5, duplicate=0.0, reorder=0.0, delay=0.0,
+                            rto=0.1, max_attempts=6),
+    "duplicate_heavy": ChaosSpec(drop=0.1, duplicate=0.6, reorder=0.1, delay=0.0,
+                                 rto=0.1, max_attempts=4),
+    "reorder_heavy": ChaosSpec(drop=0.1, duplicate=0.1, reorder=0.8, delay=0.0,
+                               rto=0.1, max_attempts=4),
+    "delay_heavy": ChaosSpec(drop=0.1, duplicate=0.1, reorder=0.2, delay=0.4,
+                             delay_factor=2.0, rto=0.1, max_attempts=4),
+}
+
+
+@pytest.fixture(scope="module")
+def chaos_payloads():
+    fed, _ = make_federated_features(
+        seed=7, n=1200, d=CHAOS_D, n_classes=CHAOS_C,
+        n_clients=CHAOS_N_CLIENTS, alpha=0.3, noise=2.0,
+    )
+    return client_payloads(fed, CHAOS_C)
+
+
+def _chaos_engine(synchronous):
+    return AsyncRoundEngine(AsyncConfig(
+        n_classes=CHAOS_C, ridge_lambda=LAMBDA, cohort=CHAOS_COHORT,
+        deadline=CHAOS_DEADLINE, staleness_rounds=CHAOS_STALENESS,
+        synchronous=synchronous, early_close=False, demote_after=10_000,
+    ))
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+@pytest.mark.parametrize("profile", sorted(CHAOS_PROFILES))
+def test_chaos_schedule_async_bitwise_equals_barrier(profile, seed, chaos_payloads):
+    """One seeded fault schedule replayed through the async engine and the
+    synchronous barrier: W bitwise equal, no upload dropped.  On failure
+    the message carries the schedule as JSON (``timeline_from_json``
+    replays it)."""
+    latency = latency_profile(
+        CHAOS_N_CLIENTS, 0.2, straggler_factor=4.0, base=0.3, jitter=0.5, seed=seed
+    )
+    cohorts = [
+        sorted(
+            np.random.default_rng((seed, r, 0xC0407))
+            .choice(CHAOS_N_CLIENTS, size=CHAOS_COHORT, replace=False)
+            .tolist()
+        )
+        for r in range(CHAOS_N_ROUNDS)
+    ]
+    spec = ChaosSpec(**{**CHAOS_PROFILES[profile].__dict__, "seed": seed})
+    events = chaos_timeline(cohorts, latency, spec)
+    schedule = timeline_to_json(cohorts, latency, spec, events)
+
+    def pf(c, r):
+        return chaos_payloads[c]
+
+    ea = _chaos_engine(synchronous=False)
+    sa, ra = run_chaos_timeline(ea, ea.init(CHAOS_D), cohorts, events, pf)
+    es = _chaos_engine(synchronous=True)
+    ss, _ = run_chaos_timeline(es, es.init(CHAOS_D), cohorts, events, pf)
+
+    assert ra["dropped_uploads"] == 0, (
+        f"{ra['dropped_uploads']} uploads fell outside the staleness window; "
+        f"schedule: {schedule}"
+    )
+    assert np.array_equal(np.asarray(sa.W), np.asarray(ss.W)), (
+        f"W diverged, max abs diff "
+        f"{np.abs(np.asarray(sa.W) - np.asarray(ss.W)).max():.3e}; "
+        f"schedule: {schedule}"
+    )
 
 
 def test_stale_upload_rejected_and_never_folds():
@@ -191,6 +273,29 @@ def test_adaptive_rounds_demote_persistent_straggler():
     )
     for cohort in rep["cohorts"][demoted_from:]:
         assert 3 not in cohort
+
+
+def test_adaptive_dropout_closes_steady_rounds_before_deadline():
+    """20% stragglers 8x slower under drop/duplicate/reorder chaos: once
+    health demotion has moved the persistent stragglers out of the
+    cohorts, the second half of the rounds completes before the deadline."""
+    n_clients, cohort, n_rounds, deadline = 24, 10, 8, 1.0
+    payloads = _payloads(n_clients=n_clients)
+    latency = latency_profile(n_clients, 0.2, straggler_factor=8.0, base=0.3,
+                              jitter=0.5, seed=2)
+    spec = ChaosSpec(drop=0.2, duplicate=0.1, reorder=0.3, rto=0.1,
+                     max_attempts=4, seed=3)
+    eng = AsyncRoundEngine(AsyncConfig(
+        n_classes=C, ridge_lambda=LAMBDA, cohort=cohort, deadline=deadline,
+        staleness_rounds=3, demote_after=2, cooldown=2 * n_rounds,
+    ))
+    _, rep = run_adaptive_rounds(
+        eng, eng.init(D), n_clients, cohort, n_rounds, latency, spec,
+        lambda c, r: payloads[c], seed=4,
+    )
+    assert rep["demoted"]
+    tail = float(np.mean(rep["completion"][n_rounds // 2:]))
+    assert tail < deadline, f"steady-state rounds closed at {tail:.3f}s"
 
 
 def test_live_classifier_tracks_open_rounds():
@@ -372,7 +477,7 @@ def test_straggler_tail_pricing():
                             deadline_s=1.0)
     assert 0.0 < out["p_straggler_round"] <= 1.0
     assert out["async_round_s"] <= out["sync_round_s"]
-    assert out["speedup"] >= 1.5  # the bench_async regime
+    assert out["speedup"] >= 1.5  # 20% stragglers 8x slower
     flat = cm.straggler_tail(16, 0.0, straggler_factor=8.0, base_s=0.3)
     assert flat["speedup"] == pytest.approx(1.0)
 

@@ -131,19 +131,3 @@ def test_temperature_calibration_prefers_sharp():
     temp, ces = calibration.calibrate_temperature(scores, ds.labels)
     assert float(temp) < 1.0
     assert ces.shape[0] == len(calibration.DEFAULT_TEMPERATURES)
-
-
-@pytest.mark.filterwarnings("ignore::DeprecationWarning")
-def test_online_woodbury_matches_batch_well_conditioned():
-    """DEPRECATED RLS path: exact on well-conditioned scales (fed3r.py caveat)."""
-    ds = make_feature_dataset(jax.random.PRNGKey(5), 200, 12, 4, noise=1.0,
-                              class_scale=1.0)
-    lam = 1.0
-    stats = fed3r.client_stats(ds.features, ds.labels, 4)
-    W_batch = fed3r.solve(stats, lam, normalize=False)
-    st = fed3r.init_online(12, 4, lam)
-    for part in np.array_split(np.arange(200), 4):
-        st = fed3r.woodbury_update(st, ds.features[part], ds.labels[part])
-    W_onl = fed3r.online_solution(st, normalize=False)
-    np.testing.assert_allclose(np.asarray(W_onl), np.asarray(W_batch),
-                               rtol=5e-3, atol=5e-3)

@@ -204,6 +204,7 @@ def test_alpha_zero_rows_of_mixed_cohort_are_bitwise_global():
     eng = PersonalizationEngine(_cfg())
     alphas = jnp.asarray([0.0, 2.0, 0.0, 1.0, 0.0, 0.5])
     heads = eng.solve_at(state, packed, alphas)
+    assert eng.dispatches == 1  # the fixed-α batched solve is one dispatch
     W_g = np.asarray(fed3r.factored_solution(state))
     for k, a in enumerate(np.asarray(alphas)):
         if a == 0.0:

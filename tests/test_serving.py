@@ -21,7 +21,7 @@ import numpy as np
 import pytest
 
 from repro.data.pipeline import make_federated_features
-from repro.federated.arrivals import pack_schedule, poisson_schedule
+from repro.federated.arrivals import pack_schedule, poisson_schedule, zipf_traffic
 from repro.federated.personalization import (
     PersonalizationEngine,
     PersonalizeConfig,
@@ -299,6 +299,29 @@ def test_slot_engine_one_dispatch_per_stage():
     eng.query(cids, _burst(fed, cids))
     assert eng.solve_dispatches == 1
     assert eng.serve_dispatches == 2
+
+
+@pytest.mark.parametrize("n_tenants", [1_000, 1_000_000])
+def test_slot_engine_serve_dispatches_independent_of_tenant_universe(n_tenants):
+    """Zipf-skewed traffic over a tenant universe folded onto the
+    federation, an arrival absorbed before each burst, two bursts drained
+    per tick: the serve stage costs exactly one dispatch per tick at every
+    universe size."""
+    fed = _fed()
+    universe = TenantUniverse(fed, n_tenants)
+    eng = _engine(universe, serve_bucket=16)
+    packed = _packed(fed, waves=6)
+    burst, n_bursts = 8, 6
+    trace = zipf_traffic(n_tenants, burst * n_bursts, exponent=1.6, seed=7)
+    for bidx in range(n_bursts):
+        eng.absorb(packed.slice_waves(bidx, bidx + 1))
+        cids = trace[bidx * burst:(bidx + 1) * burst]
+        xs = np.stack([universe.client(c).features[0] for c in cids])
+        eng.enqueue(cids, xs)
+        if bidx % 2 == 1:
+            eng.tick()
+    assert eng.ticks == n_bursts // 2
+    assert eng.serve_dispatches == eng.ticks
 
 
 def test_slot_engine_queue_overflow_sheds_at_enqueue():

@@ -2,8 +2,8 @@
 
 The engine's contract (federated/streaming_engine.py):
   * T waves fold in ONE jitted dispatch, and the factored-form final W
-    matches the batch ``solve`` in fp32 at λ ≤ 1e-2 — the regime where the
-    legacy subtractive Woodbury path visibly diverges;
+    matches the batch ``solve`` in fp32 at λ ≤ 1e-2, where a subtractive
+    update of a carried inverse would cancel;
   * the packed timeline (and hence the folded state and final W) is
     BIT-identical under permutation of a wave's concurrent arrivals
     (canonical within-wave packing);
@@ -12,9 +12,8 @@ The engine's contract (federated/streaming_engine.py):
     (regression guard for the per-arrival host loop it replaced);
   * the refresh policy: ``refresh_every=k`` re-solves W on every k-th
     wave only, with the staleness metric counting waves/samples since;
-  * the factored core state is the stable path and the subtractive
-    ``Fed3ROnline`` path is deprecated (warning) — ``online_solution``
-    routes factored states through the triangular solves.
+  * the core factored update (``factored_update``/``factored_solution``)
+    matches the batch solve.
 """
 import jax
 import jax.numpy as jnp
@@ -31,7 +30,6 @@ from repro.federated.arrivals import (
     trace_schedule,
 )
 from repro.federated.streaming_engine import (
-    ReferenceArrivalLoop,
     StreamConfig,
     StreamingEngine,
     batch_equivalent,
@@ -264,7 +262,7 @@ def test_engine_kernel_path_matches_xla_path():
 
 
 # ---------------------------------------------------------------------------
-# parity with the batch solve where the legacy path diverges (fp32, small λ)
+# parity with the batch solve at small λ (fp32)
 # ---------------------------------------------------------------------------
 
 
@@ -280,25 +278,6 @@ def test_streaming_matches_batch_solve_at_small_lambda(lam):
     err = float(jnp.max(jnp.abs(state.W - W_batch)))
     assert err <= 1e-4, f"factored engine drifted: {err:.2e}"
     assert float(state.n) == float(stats.n) == packed.n_samples
-
-
-def test_legacy_woodbury_visibly_diverges_where_engine_holds():
-    """The fix under test: same stream, λ=1e-2, fp32 — the subtractive
-    path's error is orders of magnitude above the factored engine's."""
-    packed = pack_arrival_waves(_make_stream(6, 16, lo=40, hi=80))
-    cfg = _cfg()
-    eng = StreamingEngine(cfg)
-    state, _ = eng.absorb(eng.init(D), packed)
-    legacy = ReferenceArrivalLoop(cfg)
-    W_legacy = legacy.classifier(legacy.absorb(legacy.init(D), packed))
-    W_batch, _ = batch_equivalent(packed, cfg)
-    err_fac = float(jnp.max(jnp.abs(state.W - W_batch)))
-    err_leg = float(jnp.max(jnp.abs(W_legacy - W_batch)))
-    assert legacy.dispatches == packed.n_waves  # the T-dispatch shape
-    assert err_fac <= 1e-4
-    assert err_leg > 10 * max(err_fac, 1e-7), (
-        f"expected visible legacy divergence, got {err_leg:.2e}"
-    )
 
 
 def test_streaming_is_chunk_invariant():
@@ -442,7 +421,7 @@ def test_absorb_hot_path_makes_no_host_transfers():
 
 
 # ---------------------------------------------------------------------------
-# factored core state + deprecation of the subtractive path
+# factored core state
 # ---------------------------------------------------------------------------
 
 
@@ -458,20 +437,6 @@ def test_factored_update_matches_batch_and_solution_routes():
     W_batch = fed3r.solve(stats, 1e-2)
     np.testing.assert_allclose(np.asarray(fed3r.factored_solution(st)),
                                np.asarray(W_batch), rtol=1e-4, atol=1e-5)
-    # online_solution routes factored states through the triangular solves
-    np.testing.assert_array_equal(
-        np.asarray(fed3r.online_solution(st)),
-        np.asarray(fed3r.factored_solution(st)),
-    )
-
-
-def test_subtractive_path_is_deprecated():
-    with pytest.warns(DeprecationWarning, match="CANCELS"):
-        st = fed3r.init_online(8, 3, 1e-3)  # small λ names the fp32 hazard
-    with pytest.warns(DeprecationWarning):
-        fed3r.online_solution(st)
-    with pytest.warns(DeprecationWarning):
-        fed3r.init_online(8, 3, 1.0)  # deprecated at any λ
 
 
 # ---------------------------------------------------------------------------

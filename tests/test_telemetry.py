@@ -3,7 +3,7 @@
 The layer's contract:
   * every engine's ``dispatches`` back-compat property reads/writes the
     SAME cell as the registry's ``engine_dispatches_total`` counter —
-    bitwise equal, including through the benchmarks' reset idiom;
+    bitwise equal, including through the ``dispatches = 0`` reset idiom;
   * log-bucketed histograms report p50/p99 within one bucket of the raw
     sample order statistic at any scale;
   * disabled mode is a structural no-op (shared null span, empty ring)
@@ -333,7 +333,7 @@ def test_all_four_engines_dispatch_counter_equals_legacy(registry):
         "accumulation": 2, "streaming": 1, "rounds": 1, "personalization": 1,
     }
 
-    # the benchmarks' reset idiom writes through to the registry
+    # the reset idiom writes through to the registry
     eng.dist.dispatches = 0
     assert eng.dist.dispatches == 0
     assert dispatch_summary(registry.snapshot())["accumulation"] == 0

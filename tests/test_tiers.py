@@ -40,6 +40,11 @@ needs4 = pytest.mark.skipif(
     reason="needs >=4 simulated devices "
     "(XLA_FLAGS=--xla_force_host_platform_device_count=8)",
 )
+needs8 = pytest.mark.skipif(
+    N_DEV < 8,
+    reason="needs 8 simulated devices "
+    "(XLA_FLAGS=--xla_force_host_platform_device_count=8)",
+)
 
 
 def _grid(rng, shape):
@@ -304,6 +309,88 @@ def test_mesh_tree_two_tier_bitwise_vs_two_stage():
     assert np.array_equal(outs["tree"], outs["merge"])
 
 
+def _linear_loss(params, batch):
+    logits = batch["x"] @ params["W"]
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    picked = jnp.take_along_axis(
+        logits, batch["y"][:, None].astype(jnp.int32), axis=-1
+    )[:, 0]
+    return lse - picked
+
+
+@needs8
+@pytest.mark.parametrize("shape", [(8,), (2, 4), (2, 2, 2)])
+def test_mesh_tree_bitwise_at_every_tier_shape(shape):
+    """1-, 2- and 3-tier meshes over the same 8 devices: the fp32 tree, the
+    un-routed two-stage psum and the merge backend give bitwise equal A/b
+    (statistics engine) and W (streaming, rounds, personalization), each
+    routed call in ONE dispatch."""
+    from repro.data.pipeline import (
+        pack_arrival_waves,
+        pack_client_shards,
+        pack_cohort_batches,
+        pack_personal_cohort,
+    )
+    from repro.federated.algorithms import make_algorithm
+    from repro.federated.personalization import (
+        PersonalizationEngine,
+        PersonalizeConfig,
+    )
+    from repro.federated.round_engine import RoundConfig, RoundEngine
+
+    mesh = make_tier_host_mesh(shape)
+    dists = {
+        "tree": DistConfig(aggregation="psum", mesh=mesh, donate=False,
+                           tree=mesh_tree(mesh)),
+        "flat": DistConfig(aggregation="psum", mesh=mesh, donate=False),
+    }
+
+    def clients(seed, k, n=16):
+        rng = np.random.default_rng(seed)
+        return [
+            (_grid(rng, (n, D)), rng.integers(0, C, size=n).astype(np.int32))
+            for _ in range(k)
+        ]
+
+    packed = pack_client_shards(clients(1, 4 * N_DEV), 2, mesh=mesh)
+    arrivals = pack_arrival_waves(
+        [clients(10 + w, N_DEV) for w in range(3)], mesh=mesh
+    )
+    cohort = pack_cohort_batches(clients(20, N_DEV), 8, 2, mesh=mesh)
+    tenants = clients(30, N_DEV)
+    pcohort = pack_personal_cohort(tenants, mesh=mesh)
+    fac = fed3r.factored_update(
+        fed3r.init_factored(D, C, LAM),
+        jnp.asarray(np.concatenate([x for x, _ in tenants])),
+        jnp.asarray(np.concatenate([y for _, y in tenants])),
+    )
+    params0 = {"W": jnp.zeros((D, C), jnp.float32)}
+    freeze = jax.tree.map(lambda _: 1.0, params0)
+
+    out = {}
+    for name, dist in (*dists.items(), ("merge", DistConfig())):
+        eng = AccumulationEngine(EngineConfig(n_classes=C, dist=dist))
+        acc = eng.accumulate(eng.init(D), packed)
+        s_eng = StreamingEngine(StreamConfig(n_classes=C, ridge_lambda=LAM, dist=dist))
+        st, _ = s_eng.absorb(s_eng.init(D), arrivals)
+        r_eng = RoundEngine(
+            RoundConfig(algo=make_algorithm("fedavg"), client_lr=0.1,
+                        n_total_clients=N_DEV, dist=dist),
+            _linear_loss, freeze,
+        )
+        rs = r_eng.step(r_eng.init(params0), cohort)
+        p_eng = PersonalizationEngine(PersonalizeConfig(n_classes=C, dist=dist))
+        heads = p_eng.solve_heads(fac, pcohort)
+        out[name] = (acc.stats.A, acc.stats.b, st.W, rs.params["W"], heads.W)
+        if name in dists:
+            for e in (eng, s_eng, r_eng, p_eng):
+                assert e.dispatches == 1, (name, type(e).__name__)
+    assert _bitwise(out["tree"], out["flat"])
+    # the round and personalization solves are compared to the two-stage
+    # psum only: their local cohort widths differ from the merge backend's
+    assert _bitwise(out["tree"][:3], out["merge"][:3])
+
+
 @needs4
 def test_async_engine_dist_mesh_tree_bitwise():
     """The async ring's retire folds route through the dist-owned mesh
@@ -396,6 +483,29 @@ def test_absorber_blocking_overlap_flat_bitwise():
         )
         st = eng.absorb_stats(st, s.A, s.b, s.n)
     assert np.array_equal(np.asarray(st.W), np.asarray(st_o.W))
+
+
+@pytest.mark.parametrize("fan_ins", [(8,), (4, 2), (2, 2, 2)])
+def test_absorber_blocking_one_fused_dispatch_at_every_tier_count(fan_ins):
+    """1-, 2- and 3-tier host trees over 8 leaves: the blocking absorber
+    costs one fused dispatch per segment, and its W is bitwise the flat
+    ``absorb_stats`` of each segment's sum."""
+    tree = AggregationTree(tuple(
+        TierSpec(f"t{i}", fan_in=k) for i, k in enumerate(fan_ins)
+    ))
+    segs = _segments(3, tree.leaves, seed=8)
+    st_b, disp_b = _run_absorber(tree, segs, overlap=False)
+    assert disp_b == len(segs)
+
+    eng = StreamingEngine(StreamConfig(n_classes=C, ridge_lambda=LAM))
+    st = eng.init(D)
+    for f, l, m in segs:
+        s = shard_stats(
+            jnp.asarray(f).reshape(-1, D), jnp.asarray(l).reshape(-1), C,
+            jnp.asarray(m).reshape(-1),
+        )
+        st = eng.absorb_stats(st, s.A, s.b, s.n)
+    assert np.array_equal(np.asarray(st.W), np.asarray(st_b.W))
 
 
 def test_absorber_int8_tier_paths_agree_bitwise():
