@@ -47,6 +47,7 @@ from repro.data.partition import dirichlet_partition
 from repro.data.synthetic import FeatureDataset, make_feature_dataset
 from repro.kernels.fed3r_stats import BK
 from repro.launch.mesh import data_parallel_size
+from repro.models.model import row_lengths
 
 
 def _data_parallel(
@@ -128,9 +129,12 @@ class PackedClients(NamedTuple):
     labels: np.ndarray  # (S, P, N) int32
     mask: np.ndarray  # (S, P, N) float32
     client_ids: np.ndarray  # (S, P) int32, -1 = empty slot
-    # token inputs only: (real tokens, positions computed), the non-zero ids
+    # token inputs only: (real tokens, packed positions), the non-zero ids
     # of the real rows and slots × capacity × length; None for features
     extract_tokens: Optional[Tuple[int, int]] = None
+    # token inputs only: (S, P, N) each row's length, one past its last
+    # non-zero id (``repro.models.model.row_lengths``); None for features
+    row_lengths: Optional[np.ndarray] = None
 
     @property
     def n_shards(self) -> int:
@@ -238,17 +242,19 @@ def pack_client_shards(
         slot_ids[slot] = ids[i]
 
     _count_stats_rows(sizes, n_slots * cap)
-    extract_tokens = None
-    if np.issubdtype(inputs.dtype, np.integer) and inputs.ndim == 3:  # token rows
-        extract_tokens = (int(np.count_nonzero(inputs)), int(inputs.size))
 
     def shard(a: np.ndarray) -> np.ndarray:
         return a.reshape((n_shards, clients_per_shard) + a.shape[1:])
 
+    extract_tokens = lengths = None
+    if np.issubdtype(inputs.dtype, np.integer) and inputs.ndim == 3:  # token rows
+        extract_tokens = (int(np.count_nonzero(inputs)), int(inputs.size))
+        lengths = shard(row_lengths(inputs).astype(np.int32))
+
     return PackedClients(
         inputs=shard(inputs), labels=shard(labels), mask=shard(mask),
         client_ids=slot_ids.reshape(n_shards, clients_per_shard),
-        extract_tokens=extract_tokens,
+        extract_tokens=extract_tokens, row_lengths=lengths,
     )
 
 

@@ -267,10 +267,17 @@ class AccumulationEngine(DistDispatchMixin):
 
         With a ``feature_fn`` over token inputs the call adds the packing's
         ``extract_tokens{kind=real|computed}`` to the registry: the tokens
-        the forward runs over that are real, and all it computes."""
+        the forward runs over that are real, and all it computes: what the
+        ``feature_fn``'s ``positions(lengths, seq_len)`` gives for each
+        shard's row lengths, or without one the packed capacity."""
         span = self.dist.telemetry.span
         if self.feature_fn is not None and packed.extract_tokens is not None:
             real, computed = packed.extract_tokens
+            positions = getattr(self.feature_fn, "positions", None)
+            if positions is not None:
+                seq_len = packed.inputs.shape[-1]
+                computed = sum(positions(lengths.reshape(-1), seq_len)
+                               for lengths in packed.row_lengths)
             self.dist.telemetry.counter("extract_tokens", kind="real").inc(real)
             self.dist.telemetry.counter("extract_tokens", kind="computed").inc(computed)
         with span("accumulate", engine="accumulation"):

@@ -13,10 +13,11 @@ Batch dict contract (see launch/shapes.py for the ShapeDtypeStruct specs):
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models import transformer as tfm
@@ -323,26 +324,96 @@ def feature_block_rows(cfg: ModelConfig, seq_len: int) -> int:
     return max(1, ACTIVATION_BYTES // (12 * seq_len * max(widths)))
 
 
+# Granularity of a block's length for a stack without Mamba (one with it
+# runs at multiples of its SSD chunk).
+LENGTH_STEP = 128
+
+
+class BlockPlan(NamedTuple):
+    """How :func:`token_feature_fn` runs ``n`` rows of ``seq_len`` tokens:
+    longest first, in ``blocks`` blocks of ``rows`` rows; a block whose
+    longest row needs ``k = bucket(longest)`` steps runs at ``lengths[k]``
+    positions (``k`` steps, at most ``seq_len``), and ``lengths[0] == 0``:
+    a block of padding alone is not run."""
+
+    blocks: int
+    rows: int
+    step: int
+    lengths: Tuple[int, ...]
+
+    def bucket(self, longest):
+        """Steps a block needs for its longest row (numpy or jax)."""
+        return -(-longest // self.step)
+
+
+def feature_block_plan(cfg: ModelConfig, n: int, seq_len: int) -> BlockPlan:
+    """The :class:`BlockPlan` of ``n`` rows of ``seq_len`` tokens: blocks of
+    at most :func:`feature_block_rows` rows, lengths at multiples of the SSD
+    chunk (``ssd_chunked`` needs one) or of ``LENGTH_STEP``."""
+    n_blocks = -(-n // feature_block_rows(cfg, seq_len))
+    step = min(cfg.ssm_chunk if cfg.ssm_state else LENGTH_STEP, seq_len)
+    lengths = tuple(min(k * step, seq_len) for k in range(-(-seq_len // step) + 1))
+    return BlockPlan(n_blocks, -(-n // n_blocks), step, lengths)
+
+
+def row_lengths(tokens):
+    """Each row's length (numpy or jax): one past its last non-zero id."""
+    xp = jnp if isinstance(tokens, jax.Array) else np
+    return (xp.arange(1, tokens.shape[-1] + 1) * (tokens != 0)).max(axis=-1)
+
+
+def _blocks_longest(plan: BlockPlan, desc):
+    """Each block's longest row, of the lengths sorted longest first."""
+    pad = [(0, plan.blocks * plan.rows - desc.shape[0])]
+    xp = jnp if isinstance(desc, jax.Array) else np
+    return xp.pad(desc, pad).reshape(plan.blocks, plan.rows)[:, 0]
+
+
+def feature_positions(cfg: ModelConfig, lengths: np.ndarray, seq_len: int) -> int:
+    """Positions :func:`token_feature_fn` computes for rows of these
+    ``lengths`` (:func:`row_lengths`) of ``seq_len`` tokens: the rows times
+    the length of every block it runs."""
+    plan = feature_block_plan(cfg, len(lengths), seq_len)
+    longest = _blocks_longest(plan, np.sort(lengths)[::-1])
+    return plan.rows * sum(plan.lengths[k] for k in plan.bucket(longest))
+
+
 def token_feature_fn(cfg: ModelConfig):
     """``feature_fn(params, tokens) -> (n, d_feat)`` fp32 for
     ``AccumulationEngine``: pooled features of packed token rows, id 0
     being padding (real ids are 1..V−1), so a row of padding gives zeros.
-    The rows run through the forward in blocks of at most
+
+    The rows run through the forward longest first in blocks of at most
     :func:`feature_block_rows`, one after another, so that a client's
-    activations fit whatever its rows."""
+    activations fit whatever its rows; each block runs at the length of its
+    longest row rounded up (:func:`feature_block_plan`), a block of padding
+    alone not at all.  The model is causal, so the positions cut off after
+    a row's end change none of its features.  ``fn.positions(lengths,
+    seq_len)`` is :func:`feature_positions`, for the engine's counter."""
 
     def fn(params, tokens: jax.Array) -> jax.Array:
         n, S = tokens.shape
-        n_blocks = -(-n // feature_block_rows(cfg, S))
-        rows = -(-n // n_blocks)
-        padded = jnp.pad(tokens, ((0, n_blocks * rows - n), (0, 0)))
+        plan = feature_block_plan(cfg, n, S)
+        lengths = row_lengths(tokens)
+        order = jnp.argsort(-lengths, stable=True)  # longest first
+        pad = ((0, plan.blocks * plan.rows - n), (0, 0))
+        blocks = jnp.pad(tokens[order], pad).reshape(plan.blocks, plan.rows, S)
+        longest = _blocks_longest(plan, lengths[order])
 
-        def block(t):
-            return extract_features(cfg, params, {"tokens": t, "mask": t != 0})
+        def run_at(L):
+            def run(t):
+                t = t[:, :L]
+                return extract_features(cfg, params, {"tokens": t, "mask": t != 0})
+            return run
 
-        feats = jax.lax.map(block, padded.reshape(n_blocks, rows, S))
-        return feats.reshape(n_blocks * rows, -1)[:n]
+        skip = lambda t: jnp.zeros((plan.rows, cfg.d_model), jnp.float32)
+        branches = [skip] + [run_at(L) for L in plan.lengths[1:]]
+        feats = jax.lax.map(
+            lambda b: jax.lax.switch(plan.bucket(b[1]), branches, b[0]), (blocks, longest))
+        feats = feats.reshape(plan.blocks * plan.rows, -1)[:n]
+        return jnp.zeros_like(feats).at[order].set(feats)
 
+    fn.positions = functools.partial(feature_positions, cfg)
     return fn
 
 

@@ -191,6 +191,12 @@ def test_unmasked_features_are_bit_identical(name):
     assert np.array_equal(m.extract_features(params, per_sample), want)
 
 
+def _three_row_blocks(monkeypatch, cfg, seq_len):
+    monkeypatch.setattr(model_lib, "ACTIVATION_BYTES", 3 * model_lib.ACTIVATION_BYTES
+                        // model_lib.feature_block_rows(cfg, seq_len))
+    assert model_lib.feature_block_rows(cfg, seq_len) == 3
+
+
 def test_feature_fn_runs_in_row_blocks(monkeypatch):
     """The rows go through the forward in blocks sized from the shapes;
     the result does not depend on the block size."""
@@ -199,11 +205,80 @@ def test_feature_fn_runs_in_row_blocks(monkeypatch):
     lengths = np.array([30, 7, 48, 12, 1, 22, 39])
     tokens = _tokens(np.random.default_rng(2), lengths, 48)
     whole = jax.jit(model_lib.token_feature_fn(cfg))(params, tokens)
-    monkeypatch.setattr(model_lib, "ACTIVATION_BYTES",
-                        3 * model_lib.ACTIVATION_BYTES // model_lib.feature_block_rows(cfg, 48))
-    assert model_lib.feature_block_rows(cfg, 48) == 3
+    _three_row_blocks(monkeypatch, cfg, 48)
     blocked = jax.jit(model_lib.token_feature_fn(cfg))(params, tokens)
     np.testing.assert_allclose(blocked, whole, rtol=1e-6, atol=1e-6)
+
+
+def _every_block_at_full_length(cfg, params, tokens):
+    """The forward over the rows in their own order, in blocks of
+    ``feature_block_rows`` at every position, padding blocks included."""
+    n, S = tokens.shape
+    rows = model_lib.feature_block_rows(cfg, S)
+    blocks = np.pad(tokens, ((0, -n % rows), (0, 0))).reshape(-1, rows, S)
+    block = jax.jit(lambda t: model_lib.extract_features(
+        cfg, params, {"tokens": t, "mask": t != 0}))
+    return np.concatenate([block(t) for t in blocks])[:n]
+
+
+@pytest.mark.parametrize("dtype", ["float64", "float32"])
+def test_length_bucketed_blocks_equal_the_full_length_forward(monkeypatch, dtype):
+    """Rows of mixed lengths (all-padding rows, rows ending on a chunk
+    boundary, one with a zero inside), three rows a block: sorted longest
+    first they run in blocks at 48, 32 and 16 positions and two blocks of
+    padding alone are skipped.  Each row's features, in its own place,
+    equal the forward over every block at the full 48: within 1e-6 with
+    float64 activations (the pooled features are float32), within the
+    reference test's 1e-5 with float32 ones, whose products round by the
+    positions a block holds (a one-token row moves 1.4e-6 between 16 and 48)."""
+    with jax.enable_x64(dtype == "float64"):
+        cfg = program_config(MODEL, dtype)
+        params = program_params(_weights(), cfg, jnp.dtype(dtype))
+        lengths = np.array([0, 48, 16, 0, 33, 5, 0, 32, 0, 17, 1, 0, 0])
+        tokens = _tokens(np.random.default_rng(4), lengths, 48)
+        tokens[4, 20] = 0  # a zero inside a row leaves its length 33
+        _three_row_blocks(monkeypatch, cfg, 48)
+        plan = model_lib.feature_block_plan(cfg, len(lengths), 48)
+        assert plan == (5, 3, 16, (0, 16, 32, 48))
+        np.testing.assert_array_equal(model_lib.row_lengths(tokens), lengths)
+        np.testing.assert_array_equal(model_lib.row_lengths(jnp.asarray(tokens)), lengths)
+        got = jax.jit(model_lib.token_feature_fn(cfg))(params, tokens)
+        want = _every_block_at_full_length(cfg, params, tokens)
+    assert not np.any(np.asarray(got)[lengths == 0])
+    if dtype == "float64":
+        np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+    else:
+        assert _rel(got, want) < 1e-5
+
+
+def test_extract_tokens_count_the_positions_the_blocks_run(monkeypatch):
+    """With the backbone's own feature_fn, ``computed`` is what its block
+    plan runs: the rows of each live block times the block's length."""
+    previous = get_telemetry()
+    t = Telemetry()
+    set_telemetry(t)
+    try:
+        cfg = program_config(MODEL, "float32")
+        params = program_params(_weights(), cfg, jnp.float32)
+        _three_row_blocks(monkeypatch, cfg, 48)
+        rng = np.random.default_rng(5)
+        lens = [np.array([3, 40, 16, 17, 33]), np.array([16, 2])]
+        clients = [(_tokens(rng, n, 48), np.arange(len(n)) % 2) for n in lens]
+        packed = pack_client_shards(clients, 1, round_to=4)
+        assert packed.extract_tokens == (127, 2 * 8 * 48)
+        np.testing.assert_array_equal(packed.row_lengths[:, 0], [
+            [3, 40, 16, 17, 33, 0, 0, 0], [16, 2, 0, 0, 0, 0, 0, 0]])
+        # 8 rows a client: 3 blocks of 3.  First client: [40 33 17] at 48,
+        # [16 3 0] at 16, [0 0 0] skipped; second: [16 2 0] at 16, two skipped
+        computed = 3 * 48 + 3 * 16 + 3 * 16
+        eng = AccumulationEngine(EngineConfig(n_classes=2),
+                                 feature_fn=model_lib.token_feature_fn(cfg))
+        jax.block_until_ready(eng.accumulate(eng.init(cfg.d_model), packed, params))
+        counts = {c["labels"]["kind"]: c["value"] for c in t.snapshot()["counters"]
+                  if c["name"] == "extract_tokens"}
+        assert counts == {"real": 127, "computed": computed}
+    finally:
+        set_telemetry(previous)
 
 
 def test_extract_token_counters():
